@@ -18,7 +18,8 @@ Consumers: ``repro.core.machine`` (thin execution facade),
 
 from repro.arch.overlay import IDENTITY, Overlay, overlay_grid  # noqa: F401
 from repro.arch.registry import (UnknownDeviceError,  # noqa: F401
-                                 get_device, list_devices, register_device)
+                                 device_for_kind, get_device, list_devices,
+                                 register_device)
 from repro.arch.select import (HLO_DTYPE_TO_IN, best_mfma,  # noqa: F401
                                best_mfma_for_hlo, throughput_ranking)
 from repro.arch.spec import (CycleEntry, DeviceSpec,  # noqa: F401
@@ -27,7 +28,8 @@ from repro.arch.spec import (CycleEntry, DeviceSpec,  # noqa: F401
 __all__ = [
     "CycleEntry", "DeviceSpec", "Interconnect", "MemoryHierarchy",
     "Overlay", "IDENTITY", "overlay_grid",
-    "UnknownDeviceError", "get_device", "list_devices", "register_device",
+    "UnknownDeviceError", "device_for_kind", "get_device", "list_devices",
+    "register_device",
     "HLO_DTYPE_TO_IN", "best_mfma", "best_mfma_for_hlo",
     "throughput_ranking",
 ]

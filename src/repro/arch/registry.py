@@ -22,6 +22,7 @@ __all__ = [
     "MI300_CYCLES",
     "register_device",
     "get_device",
+    "device_for_kind",
     "list_devices",
     "UnknownDeviceError",
 ]
@@ -162,6 +163,24 @@ def get_device(name: str) -> DeviceSpec:
 
 def list_devices() -> Iterable[str]:
     return sorted(_REGISTRY)
+
+
+#: ``jax.Device.device_kind`` -> catalog name, for the chips this repo
+#: has run on.  A kind not listed here is an error, never a default.
+DEVICE_KINDS: Dict[str, str] = {
+    "TPU v5 lite": "tpu_v5e",
+}
+
+
+def device_for_kind(kind: str) -> DeviceSpec:
+    """The catalog spec of the chip JAX reports as ``kind``."""
+    try:
+        return get_device(DEVICE_KINDS[kind])
+    except KeyError:
+        raise UnknownDeviceError(
+            f"unknown device_kind {kind!r}; known: {sorted(DEVICE_KINDS)} "
+            "(add the chip's kind to repro.arch.registry.DEVICE_KINDS)"
+        ) from None
 
 
 for _spec in (MI200, MI300, MI300X, TPU_V5E, TPU_V5P):

@@ -1,22 +1,16 @@
-"""Version-adaptive Pallas/TPU shim: the ONE place that touches ``pltpu``.
+"""The ONE place that touches ``jax.experimental.pallas.tpu``.
 
-JAX has renamed pieces of the Pallas TPU surface across the 0.4.x line —
-most notably the compiler-parameters dataclass, spelled
-``pltpu.TPUCompilerParams`` up to ~0.4.3x and ``pltpu.CompilerParams``
-afterwards.  Every kernel in ``repro.kernels`` used to call one spelling
-directly, so an unpinned ``jax[cpu]`` silently killed the whole compute
-layer with ``AttributeError`` at trace time (34 red tests).
+Every kernel in ``repro.kernels`` builds its TPU-specific pieces through
+this module, spelled for the one supported JAX (``requirements.txt``):
 
-All five kernels now route through this module instead:
-
-* :func:`tpu_compiler_params` — dimension-semantics compiler params under
-  either spelling, with a clear error naming the installed JAX version if
-  neither exists;
+* :func:`tpu_compiler_params` — dimension-semantics compiler params;
 * :func:`vmem` / :func:`smem_block_spec` — VMEM scratch shapes and
   SMEM-resident block specs;
-* :func:`default_interpret` / :func:`resolve_interpret` — backend
-  detection for interpret-mode-on-CPU (the container has no TPU; the same
-  call sites compile to Mosaic on real hardware).
+* :func:`prefetch_grid_spec` — the scalar-prefetch grid the paged
+  decode kernel gathers through;
+* :func:`default_interpret` / :func:`resolve_interpret` — interpret mode
+  wherever the backend is not a TPU (CPU tests); on a TPU the same call
+  sites compile to Mosaic.
 
 Nothing outside this file may import ``jax.experimental.pallas.tpu``.
 """
@@ -30,7 +24,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
-    "PallasCompatError",
     "tpu_compiler_params",
     "vmem",
     "smem_block_spec",
@@ -38,25 +31,6 @@ __all__ = [
     "default_interpret",
     "resolve_interpret",
 ]
-
-#: Spellings of the TPU compiler-params dataclass, newest first.
-_COMPILER_PARAMS_NAMES = ("CompilerParams", "TPUCompilerParams")
-
-
-class PallasCompatError(RuntimeError):
-    """The installed JAX exposes none of the known Pallas TPU spellings."""
-
-
-def _compiler_params_cls():
-    for name in _COMPILER_PARAMS_NAMES:
-        cls = getattr(pltpu, name, None)
-        if cls is not None:
-            return cls
-    raise PallasCompatError(
-        f"jax {jax.__version__}: jax.experimental.pallas.tpu exposes "
-        f"neither of {_COMPILER_PARAMS_NAMES} — repro.kernels supports "
-        "jax>=0.4.30,<0.5 (see requirements.txt); install a version in "
-        "that range or add the new spelling to repro.kernels.compat")
 
 
 def tpu_compiler_params(*, dimension_semantics: Sequence[str]):
@@ -66,8 +40,7 @@ def tpu_compiler_params(*, dimension_semantics: Sequence[str]):
     order / in parallel) or ``"arbitrary"`` (sequential — carries VMEM
     scratch state across steps, e.g. a K loop's accumulator).
     """
-    return _compiler_params_cls()(
-        dimension_semantics=tuple(dimension_semantics))
+    return pltpu.CompilerParams(dimension_semantics=tuple(dimension_semantics))
 
 
 def vmem(shape: Tuple[int, ...], dtype):
@@ -75,12 +48,10 @@ def vmem(shape: Tuple[int, ...], dtype):
     return pltpu.VMEM(shape, dtype)
 
 
-def smem_block_spec(block_shape: Optional[Tuple[int, ...]] = None,
-                    index_map=None) -> pl.BlockSpec:
-    """A BlockSpec placing the operand in SMEM (scalars / tiny tables)."""
-    if block_shape is None and index_map is None:
-        return pl.BlockSpec(memory_space=pltpu.SMEM)
-    return pl.BlockSpec(block_shape, index_map, memory_space=pltpu.SMEM)
+def smem_block_spec() -> pl.BlockSpec:
+    """A BlockSpec placing the whole operand in SMEM (scalars / tiny
+    tables the kernel indexes by grid position)."""
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def prefetch_grid_spec(*, num_scalar_prefetch: int, grid, in_specs,
@@ -88,17 +59,11 @@ def prefetch_grid_spec(*, num_scalar_prefetch: int, grid, in_specs,
     """A grid spec whose first ``num_scalar_prefetch`` operands are SMEM
     scalars available *before* the kernel body runs — index maps receive
     them as trailing refs, so block indices can be data-dependent (the
-    paged-attention block-table gather).  Raises :class:`PallasCompatError`
-    if the installed JAX predates scalar prefetch."""
-    cls = getattr(pltpu, "PrefetchScalarGridSpec", None)
-    if cls is None:
-        raise PallasCompatError(
-            f"jax {jax.__version__}: jax.experimental.pallas.tpu has no "
-            "PrefetchScalarGridSpec — repro.kernels needs jax>=0.4.30,<0.5 "
-            "(see requirements.txt) for the paged decode-attention kernel")
-    return cls(num_scalar_prefetch=num_scalar_prefetch, grid=tuple(grid),
-               in_specs=list(in_specs), out_specs=out_specs,
-               scratch_shapes=list(scratch_shapes))
+    paged-attention block-table gather)."""
+    return pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=num_scalar_prefetch, grid=tuple(grid),
+        in_specs=list(in_specs), out_specs=out_specs,
+        scratch_shapes=list(scratch_shapes))
 
 
 def default_interpret() -> bool:

@@ -12,8 +12,10 @@ Two variants share one kernel body:
 
 * :func:`paged_decode_attention` — the block-paged cache the
   continuous-batching serve engine uses.  K/V live in a shared pool of
-  fixed-size blocks ``(P, block_kv, KV, hd)``; each request names its
-  blocks via a ``(B, NB)`` block table.  The table and the per-request
+  fixed-size blocks ``(P, KV, block_kv, hd)`` — head-major inside a
+  block, so one head's page is a contiguous, (8, 128)-tileable
+  ``(block_kv, hd)`` slab; each request names its blocks via a
+  ``(B, NB)`` block table.  The table and the per-request
   lengths ride in as scalar-prefetch operands
   (``compat.prefetch_grid_spec``), so the K/V BlockSpec index maps
   gather ``pool[table[b, j]]`` per grid step — the same ``kv_len`` mask
@@ -65,8 +67,8 @@ def _decode_body(kv_len, ki, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
         corr = jnp.exp(m_prev - m_new)
         l_ref[...] = l_ref[...] * corr + jnp.sum(p, -1, keepdims=True)
         acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ()))
-        ).astype(jnp.float32)
+            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
     @pl.when(ki == n_kv - 1)
@@ -160,7 +162,7 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
                            v_pool: jax.Array, block_tables: jax.Array,
                            kv_len: jax.Array, *,
                            interpret: bool = False) -> jax.Array:
-    """q: (B, H, hd); k_pool/v_pool: (P, block_kv, KV, hd);
+    """q: (B, H, hd); k_pool/v_pool: (P, KV, block_kv, hd);
     block_tables: (B, NB) int32 physical block ids; kv_len: (B,) int32.
 
     Each request attends its first ``kv_len[b]`` cache positions, read
@@ -172,7 +174,7 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     gather runs before the ``pl.when`` mask skips the compute.
     """
     B, H, hd = q.shape
-    P, block_kv, KV = k_pool.shape[0], k_pool.shape[1], k_pool.shape[2]
+    KV, block_kv = k_pool.shape[1], k_pool.shape[2]
     NB = block_tables.shape[1]
     G = H // KV
     T = NB * block_kv
@@ -185,18 +187,18 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     lens = _lens_vector(kv_len, B)
 
     def _kv_index(i, j, tbl_ref, len_ref):
-        # gather: grid step (i, j) reads physical block table[b, j] of
-        # kv head i % KV (block dims: (1, block_kv, 1, hd))
+        # gather: grid step (i, j) reads kv head i % KV of physical block
+        # table[b, j] — one contiguous (block_kv, hd) page
         del len_ref
-        return (tbl_ref[i // KV, j], 0, i % KV, 0)
+        return (tbl_ref[i // KV, j], i % KV, 0, 0)
 
     grid_spec = compat.prefetch_grid_spec(
         num_scalar_prefetch=2,
         grid=(B * KV, NB),
         in_specs=[
             pl.BlockSpec((1, G, hd), lambda i, j, t, n: (i, 0, 0)),
-            pl.BlockSpec((1, block_kv, 1, hd), _kv_index),
-            pl.BlockSpec((1, block_kv, 1, hd), _kv_index),
+            pl.BlockSpec((1, None, block_kv, hd), _kv_index),
+            pl.BlockSpec((1, None, block_kv, hd), _kv_index),
         ],
         out_specs=pl.BlockSpec((1, G, hd), lambda i, j, t, n: (i, 0, 0)),
         scratch_shapes=[
@@ -206,16 +208,9 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
         ],
     )
 
-    def _kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                m_ref, l_ref, acc_ref):
-        _paged_decode_kernel(
-            tbl_ref, len_ref, q_ref,
-            k_ref.reshape(1, block_kv, hd), v_ref.reshape(1, block_kv, hd),
-            o_ref, m_ref, l_ref, acc_ref, scale=scale, n_kv=NB,
-            block_kv=block_kv, kv_heads=KV)
-
     out = pl.pallas_call(
-        _kernel,
+        functools.partial(_paged_decode_kernel, scale=scale, n_kv=NB,
+                          block_kv=block_kv, kv_heads=KV),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * KV, G, hd), q.dtype),
         compiler_params=compat.tpu_compiler_params(

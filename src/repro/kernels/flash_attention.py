@@ -70,8 +70,8 @@ def _flash_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
         corr = jnp.exp(m_prev - m_new)
         l_ref[...] = l_ref[...] * corr + jnp.sum(p, -1, keepdims=True)
         acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ()))
-        ).astype(jnp.float32)
+            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
     @pl.when(ki == n_kv - 1)

@@ -5,10 +5,13 @@ SSM state (hd, ds) lives in f32 VMEM scratch across chunk steps (reset at
 chunk 0).  All intra-chunk work is expressed as (Q x Q) / (Q x hd) / (Q x ds)
 matmuls — MXU-shaped, which is precisely the "state-space duality" insight:
 the quadratic-attention form of the SSM inside a chunk, the linear
-recurrence across chunks.  Cumulative sums are computed as a
-lower-triangular-ones matmul (MXU) rather than a serial scan.
+recurrence across chunks.  Cumulative sums are masked (Q x Q) reductions
+rather than a serial scan.
 
-B/C group tensors are indexed per-head via the BlockSpec index map
+Operands are passed head-major ((B, nh, S, hd), dt as a (B, nh, S, 1)
+column), so every block's last two dims are a (chunk, width) slab the
+TPU's (8, 128) tiling accepts; the per-head decay ``A`` sits whole in
+SMEM.  B/C group tensors are indexed per-head via the BlockSpec index map
 (h -> h // heads_per_group), so grouped B/C are never materialised per head.
 """
 
@@ -34,41 +37,47 @@ def _ssd_kernel(a_ref, x_ref, dt_ref, b_ref, c_ref, y_ref, hout_ref,
     def _init():
         state_ref[...] = jnp.zeros_like(state_ref)
 
-    A = a_ref[0, 0]                                     # scalar f32
-    x = x_ref[0, :, 0, :].astype(jnp.float32)           # (Q, hd)
-    dt = dt_ref[0, :, 0].astype(jnp.float32)            # (Q,)
-    Bm = b_ref[0, :, 0, :].astype(jnp.float32)          # (Q, ds)
-    Cm = c_ref[0, :, 0, :].astype(jnp.float32)          # (Q, ds)
+    A = a_ref[pl.program_id(1)]                         # scalar f32 (SMEM)
+    x = x_ref[...].astype(jnp.float32)                  # (Q, hd)
+    dt = dt_ref[...].astype(jnp.float32)                # (Q, 1) column
+    Bm = b_ref[...].astype(jnp.float32)                 # (Q, ds)
+    Cm = c_ref[...].astype(jnp.float32)                 # (Q, ds)
 
-    dA = dt * A                                         # (Q,) log-decay <= 0
+    # per-step vectors stay 2-D ((Q, 1) columns, (1, Q) rows), the
+    # shapes Mosaic lays out on vregs; the cumsums are masked reductions
+    dA = dt * A                                         # (Q, 1) log-decay <= 0
     row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    tri = (col <= row).astype(jnp.float32)              # inclusive lower-tri
-    cum = jax.lax.dot_general(tri, dA[:, None],
-                              (((1,), (0,)), ((), ())))[:, 0]   # cumsum via MXU
-    total = cum[chunk - 1]
+    dA_row = jnp.sum(jnp.where(row == col, dA, 0.0), axis=0,
+                     keepdims=True)                     # (1, Q)
+    causal = col <= row                                 # inclusive lower-tri
+    cum = jnp.sum(jnp.where(causal, dA_row, 0.0), axis=1,
+                  keepdims=True)                        # (Q, 1) cumsum
+    cum_row = jnp.sum(jnp.where(row <= col, dA, 0.0), axis=0,
+                      keepdims=True)                    # (1, Q) cumsum
+    total = jnp.sum(dA, axis=0, keepdims=True)          # (1, 1)
 
     scores = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())))  # (Q, Q)
     # mask inside the exp: anti-causal entries are positive log-decays
     # whose exp overflows (inf * 0 = NaN)
-    L = jnp.exp(jnp.where(tri > 0, cum[:, None] - cum[None, :], -1e30))
-    W = scores * L * dt[None, :]
-    y_intra = jax.lax.dot_general(W, x, (((1,), (0,)), ((), ())))   # (Q, hd)
+    L = jnp.exp(jnp.where(causal, cum - cum_row, -1e30))
+    y_intra = jax.lax.dot_general(scores * L, x * dt,
+                                  (((1,), (0,)), ((), ())))         # (Q, hd)
 
     h_prev = state_ref[...]                              # (hd, ds)
     y_inter = jax.lax.dot_general(Cm, h_prev,
                                   (((1,), (1,)), ((), ())))         # (Q, hd)
-    y_inter = y_inter * jnp.exp(cum)[:, None]
+    y_inter = y_inter * jnp.exp(cum)
 
-    decay_j = jnp.exp(total - cum) * dt                  # (Q,)
+    decay = jnp.exp(total - cum) * dt                    # (Q, 1)
     state_ref[...] = jnp.exp(total) * h_prev + jax.lax.dot_general(
-        x * decay_j[:, None], Bm, (((0,), (0,)), ((), ())))         # (hd, ds)
+        x * decay, Bm, (((0,), (0,)), ((), ())))                    # (hd, ds)
 
-    y_ref[0, :, 0, :] = (y_intra + y_inter).astype(y_ref.dtype)
+    y_ref[...] = (y_intra + y_inter).astype(y_ref.dtype)
 
     @pl.when(ci == n_chunks - 1)
     def _emit_state():
-        hout_ref[0, 0] = state_ref[...]
+        hout_ref[...] = state_ref[...]
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -93,23 +102,30 @@ def mamba2_ssd(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
         functools.partial(_ssd_kernel, n_chunks=n_chunks, chunk=chunk),
         grid=grid,
         in_specs=[
-            compat.smem_block_spec((1, 1), lambda b, h, c: (h, 0)),
-            pl.BlockSpec((1, chunk, 1, hd), lambda b, h, c: (b, c, h, 0)),
-            pl.BlockSpec((1, chunk, 1), lambda b, h, c: (b, c, h)),
-            pl.BlockSpec((1, chunk, 1, ds), lambda b, h, c: (b, c, h // hpg, 0)),
-            pl.BlockSpec((1, chunk, 1, ds), lambda b, h, c: (b, c, h // hpg, 0)),
+            compat.smem_block_spec(),
+            pl.BlockSpec((None, None, chunk, hd),
+                         lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((None, None, chunk, 1),
+                         lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((None, None, chunk, ds),
+                         lambda b, h, c: (b, h // hpg, c, 0)),
+            pl.BlockSpec((None, None, chunk, ds),
+                         lambda b, h, c: (b, h // hpg, c, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, chunk, 1, hd), lambda b, h, c: (b, c, h, 0)),
-            pl.BlockSpec((1, 1, hd, ds), lambda b, h, c: (b, h, 0, 0)),
+            pl.BlockSpec((None, None, chunk, hd),
+                         lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((None, None, hd, ds), lambda b, h, c: (b, h, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, S, nh, hd), x.dtype),
+            jax.ShapeDtypeStruct((B, nh, S, hd), x.dtype),
             jax.ShapeDtypeStruct((B, nh, hd, ds), jnp.float32),
         ],
         scratch_shapes=[compat.vmem((hd, ds), jnp.float32)],
         compiler_params=compat.tpu_compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(A.reshape(nh, 1).astype(jnp.float32), x, dt, Bm, Cm)
-    return y, state
+    )(A.astype(jnp.float32), x.transpose(0, 2, 1, 3),
+      dt.transpose(0, 2, 1)[..., None], Bm.transpose(0, 2, 1, 3),
+      Cm.transpose(0, 2, 1, 3))
+    return y.transpose(0, 2, 1, 3), state

@@ -38,13 +38,9 @@ from __future__ import annotations
 
 from typing import Dict, Mapping, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-
-try:  # jax < 0.5 (the supported floor)
-    from jax.experimental.shard_map import shard_map as _shard_map
-except ImportError:  # newer jax moved it to the top level
-    from jax import shard_map as _shard_map
 
 from repro.kernels import (compat, decode_attention as _da,
                            flash_attention as _fa, mamba2_ssd as _ssd,
@@ -156,13 +152,13 @@ def flash_attention(q, k, v, *, causal=True, kv_len=None, device=None,
                                    interpret=interpret)
 
         if kv_len is None:
-            fn = _shard_map(_body, mesh=mesh, in_specs=qkv_specs,
-                            out_specs=qkv_specs[0], check_rep=False)
+            fn = jax.shard_map(_body, mesh=mesh, in_specs=qkv_specs,
+                               out_specs=qkv_specs[0], check_vma=False)
             return fn(q, k, v)
         lens = jnp.asarray(kv_len, jnp.int32)
         len_spec = asn.spec("B") if lens.ndim else P()
-        fn = _shard_map(_body, mesh=mesh, in_specs=qkv_specs + (len_spec,),
-                        out_specs=qkv_specs[0], check_rep=False)
+        fn = jax.shard_map(_body, mesh=mesh, in_specs=qkv_specs + (len_spec,),
+                           out_specs=qkv_specs[0], check_vma=False)
         return fn(q, k, v, lens)
     plan, blocks = _resolve("flash_attention", plan,
                             {"B": B, "S": S, "T": T, "H": H,
@@ -203,12 +199,12 @@ def decode_attention(q, k, v, kv_len, *, device=None,
                                     block_kv=block_kv, pad=True,
                                     interpret=interpret)
 
-        fn = _shard_map(_body, mesh=mesh,
-                        in_specs=(asn.spec("B", "H", None),
-                                  asn.spec("B", None, "KV", None),
-                                  asn.spec("B", None, "KV", None),
-                                  asn.spec("B")),
-                        out_specs=asn.spec("B", "H", None), check_rep=False)
+        fn = jax.shard_map(_body, mesh=mesh,
+                           in_specs=(asn.spec("B", "H", None),
+                                     asn.spec("B", None, "KV", None),
+                                     asn.spec("B", None, "KV", None),
+                                     asn.spec("B")),
+                           out_specs=asn.spec("B", "H", None), check_vma=False)
         return fn(q, k, v, lens)
     plan, blocks = _resolve("decode_attention", plan,
                             {"B": B, "T": T, "H": H, "KV": k.shape[2],
@@ -229,7 +225,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, kv_len, *,
                            interpret: Optional[bool] = None):
     """Flash-decode over a block-paged KV pool.
 
-    q (B, H, hd); k_pool/v_pool (P, page, KV, hd); block_tables (B, NB)
+    q (B, H, hd); k_pool/v_pool (P, KV, page, hd); block_tables (B, NB)
     int32 physical block ids; kv_len (B,) int32 per-request lengths.
     The pool's page size IS the kv tile, so the plan's ``block_kv`` must
     equal it — the ``shapes["page"]`` pin makes the planner agree on
@@ -237,7 +233,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, kv_len, *,
     construction via :class:`~repro.serve.PagedKVCache`).
     """
     B, H, hd = q.shape
-    page, KV = k_pool.shape[1], k_pool.shape[2]
+    KV, page = k_pool.shape[1], k_pool.shape[2]
     NB = block_tables.shape[1]
     plan, blocks = _resolve("paged_decode_attention", plan,
                             {"B": B, "T": NB * page, "H": H, "KV": KV,
@@ -269,15 +265,15 @@ def mamba2_ssd(x, dt, A, Bm, Cm, *, device=None,
             return mamba2_ssd(xl, dtl, Al, Bl, Cl, device=device,
                               chunk=chunk, pad=True, interpret=interpret)
 
-        fn = _shard_map(_body, mesh=mesh,
-                        in_specs=(asn.spec("B", None, "nh", None),
-                                  asn.spec("B", None, "nh"),
-                                  asn.spec("nh"),
-                                  asn.spec("B", None, "G", None),
-                                  asn.spec("B", None, "G", None)),
-                        out_specs=(asn.spec("B", None, "nh", None),
-                                   asn.spec("B", "nh", None, None)),
-                        check_rep=False)
+        fn = jax.shard_map(_body, mesh=mesh,
+                           in_specs=(asn.spec("B", None, "nh", None),
+                                     asn.spec("B", None, "nh"),
+                                     asn.spec("nh"),
+                                     asn.spec("B", None, "G", None),
+                                     asn.spec("B", None, "G", None)),
+                           out_specs=(asn.spec("B", None, "nh", None),
+                                      asn.spec("B", "nh", None, None)),
+                           check_vma=False)
         return fn(x, dt, A, Bm, Cm)
     plan, blocks = _resolve("mamba2_ssd", plan,
                             {"B": B, "S": S, "nh": nh, "hd": hd,
@@ -311,11 +307,11 @@ def moe_gmm(x, w, *, device=None, plan: Optional[TilePlan] = None,
                            block_n=block_n, block_k=block_k, pad=True,
                            interpret=interpret)
 
-        fn = _shard_map(_body, mesh=mesh,
-                        in_specs=(asn.spec("E", None, None),
-                                  asn.spec("E", None, None)),
-                        out_specs=asn.spec("E", None, None),
-                        check_rep=False)
+        fn = jax.shard_map(_body, mesh=mesh,
+                           in_specs=(asn.spec("E", None, None),
+                                     asn.spec("E", None, None)),
+                           out_specs=asn.spec("E", None, None),
+                           check_vma=False)
         return fn(x, w)
     plan, blocks = _resolve("moe_gmm", plan,
                             {"E": E, "C": C, "K": K, "N": N},

@@ -60,13 +60,16 @@ def decode_attention_ref(q, k, v, kv_len):
 
 def paged_decode_attention_ref(q, k_pool, v_pool, block_tables, kv_len):
     """Oracle for the paged kernel: gather each request's blocks from the
-    (P, bs, KV, hd) pool into a dense (B, NB*bs, KV, hd) cache, then run
+    (P, KV, bs, hd) pool into a dense (B, NB*bs, KV, hd) cache, then run
     the plain decode oracle with per-request lengths."""
     B = q.shape[0]
-    bs, KV, hd = k_pool.shape[1], k_pool.shape[2], k_pool.shape[3]
-    k = k_pool[block_tables].reshape(B, -1, KV, hd)
-    v = v_pool[block_tables].reshape(B, -1, KV, hd)
-    return decode_attention_ref(q, k, v, kv_len)
+    KV, hd = k_pool.shape[1], k_pool.shape[3]
+
+    def dense(pool):                 # (B, NB, KV, bs, hd) -> (B, T, KV, hd)
+        return pool[block_tables].transpose(0, 1, 3, 2, 4).reshape(
+            B, -1, KV, hd)
+
+    return decode_attention_ref(q, dense(k_pool), dense(v_pool), kv_len)
 
 
 def mamba2_ssd_ref(x, dt, A, Bm, Cm):

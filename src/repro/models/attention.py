@@ -371,11 +371,18 @@ def attn_decode(cfg: ModelConfig, w, x: jax.Array, cache: Dict,
     return y, {"k": k, "v": v}
 
 
+def _gather_pages(pool: jax.Array, tables: jax.Array) -> jax.Array:
+    """(P, KV, page, hd) pool + (B, NB) tables -> the dense (B, NB*page,
+    KV, hd) cache holding each request's blocks in table order."""
+    B, KV, hd = tables.shape[0], pool.shape[1], pool.shape[3]
+    return pool[tables].transpose(0, 1, 3, 2, 4).reshape(B, -1, KV, hd)
+
+
 def _paged_attention_kernel(q, k_pool, v_pool, tables, kv_len, *,
                             device=None):
     """Try the paged Pallas kernel; ``None`` means "gather + reference"."""
     B, S, H, hd = q.shape
-    page, KV = k_pool.shape[1], k_pool.shape[2]
+    KV, page = k_pool.shape[1], k_pool.shape[2]
     NB = tables.shape[1]
     dec = kdispatch.decide(
         "paged_decode_attention",
@@ -393,7 +400,7 @@ def attn_decode_paged(cfg: ModelConfig, w, x: jax.Array, cache: Dict,
     """One continuous-batching decode step against the shared KV pool.
 
     x: (B, 1, D) — each row is a *different* request's pending token;
-    cache ``{"k", "v"}``: (P, page, KV, hd) block pools; block_tables:
+    cache ``{"k", "v"}``: (P, KV, page, hd) block pools; block_tables:
     (B, NB) int32 physical block ids (unused tail slots must point at the
     engine's reserved null block 0); lens: (B,) int32 tokens already in
     each request's cache — both the new token's write position and its
@@ -403,18 +410,16 @@ def attn_decode_paged(cfg: ModelConfig, w, x: jax.Array, cache: Dict,
     B, S, D = x.shape
     lens = jnp.asarray(lens, jnp.int32)
     q, k_new, v_new = _qkv(cfg, w, x, lens[:, None])
-    P, page, KV, hd = cache["k"].shape
+    page = cache["k"].shape[2]
     tables = jnp.asarray(block_tables, jnp.int32)
     # scatter the new K/V row into pool block table[b, lens//page] at
     # row lens%page — requests own disjoint blocks, so rows never collide
     # (idle engine slots all hit the null block, whose content is never
     # attended unmasked)
     slot = jnp.take_along_axis(tables, (lens // page)[:, None], axis=1)[:, 0]
-    idx = slot * page + lens % page
-    k = cache["k"].reshape(P * page, KV, hd).at[idx].set(
-        k_new[:, 0]).reshape(P, page, KV, hd)
-    v = cache["v"].reshape(P * page, KV, hd).at[idx].set(
-        v_new[:, 0]).reshape(P, page, KV, hd)
+    row = lens % page
+    k = cache["k"].at[slot, :, row].set(k_new[:, 0])
+    v = cache["v"].at[slot, :, row].set(v_new[:, 0])
     kv_len = lens + 1
     out = None
     if cfg.use_pallas:
@@ -424,9 +429,8 @@ def attn_decode_paged(cfg: ModelConfig, w, x: jax.Array, cache: Dict,
         # gather the tables into a dense (B, NB*page, KV, hd) cache and
         # run the plain decode path (which may still pick the contiguous
         # kernel when cfg.use_pallas is set)
-        kd = k[tables].reshape(B, -1, KV, hd)
-        vd = v[tables].reshape(B, -1, KV, hd)
-        out = attention(q, kd, vd, causal=False, kv_len=kv_len,
+        out = attention(q, _gather_pages(k, tables),
+                        _gather_pages(v, tables), causal=False, kv_len=kv_len,
                         use_pallas=cfg.use_pallas,
                         pallas_device=cfg.pallas_device)
     y = dense(out.reshape(B, S, cfg.n_heads * cfg.hd), w["wo"])
@@ -441,7 +445,7 @@ def attn_prefill_paged(cfg: ModelConfig, w, x: jax.Array, cache: Dict,
 
     x: (B, C, D) — a fixed-size chunk of each request's *uncached* prompt
     suffix, right-padded past ``n_valid``; cache ``{"k", "v"}``: the
-    (P, page, KV, hd) block pools; block_tables (B, NB) / lens (B,) as in
+    (P, KV, page, hd) block pools; block_tables (B, NB) / lens (B,) as in
     :func:`attn_decode_paged` — ``lens`` is the number of tokens already
     in the cache, i.e. the chunk's global start position (both its write
     offset and its RoPE base).  The chunk's K/V rows are written into
@@ -467,41 +471,38 @@ def attn_prefill_paged(cfg: ModelConfig, w, x: jax.Array, cache: Dict,
     nv = jnp.asarray(n_valid, jnp.int32)
     positions = lens[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]
     q, k_new, v_new = _qkv(cfg, w, x, positions)
-    P, page, KV, hd = cache["k"].shape
+    KV, page = cache["k"].shape[1], cache["k"].shape[2]
     tables = jnp.asarray(block_tables, jnp.int32)
     if aligned and B == 1 and C <= page:
-        # single-block chunk: one contiguous C-row window in the flat pool
-        start = tables[0, lens[0] // page] * page + lens[0] % page
+        # single-block chunk: one contiguous C-row window per head
+        start = (tables[0, lens[0] // page], 0, lens[0] % page, 0)
         k = jax.lax.dynamic_update_slice(
-            cache["k"].reshape(P * page, KV, hd),
-            k_new.reshape(C, KV, hd), (start, 0, 0)).reshape(P, page, KV, hd)
+            cache["k"], k_new.transpose(0, 2, 1, 3), start)
         v = jax.lax.dynamic_update_slice(
-            cache["v"].reshape(P * page, KV, hd),
-            v_new.reshape(C, KV, hd), (start, 0, 0)).reshape(P, page, KV, hd)
+            cache["v"], v_new.transpose(0, 2, 1, 3), start)
     else:
         # scatter the chunk's K/V rows at their global positions; rows
         # past n_valid (chunk padding) are redirected to the null block,
         # whose content is never attended unmasked
-        blk = jnp.take_along_axis(tables, positions // page, axis=1)
-        idx = blk * page + positions % page
         row = jnp.arange(C, dtype=jnp.int32)[None, :]
-        idx = jnp.where(row < nv[:, None], idx, row % page)
-        k = cache["k"].reshape(P * page, KV, hd).at[idx.reshape(-1)].set(
-            k_new.reshape(B * C, KV, hd)).reshape(P, page, KV, hd)
-        v = cache["v"].reshape(P * page, KV, hd).at[idx.reshape(-1)].set(
-            v_new.reshape(B * C, KV, hd)).reshape(P, page, KV, hd)
+        valid = row < nv[:, None]
+        blk = jnp.where(valid, jnp.take_along_axis(
+            tables, positions // page, axis=1), 0)
+        r = jnp.where(valid, positions % page, row % page)
+        k = cache["k"].at[blk, :, r].set(k_new)
+        v = cache["v"].at[blk, :, r].set(v_new)
     # read path: gather the table into a dense (B, NB*page, KV, hd) cache
     # (exactly the decode tick's read) and attend causally at each
     # request's own offset.  kv_len additionally masks rows the causal
     # mask cannot see when C == 1; for valid rows it masks a subset of
     # what causality already does, so the attended logits are unchanged.
-    kd = k[tables].reshape(B, -1, KV, hd)
-    vd = v[tables].reshape(B, -1, KV, hd)
+    kd = _gather_pages(k, tables)
+    vd = _gather_pages(v, tables)
     G = cfg.n_heads // KV
     if G > 1:
         kd = jnp.repeat(kd, G, axis=2)
         vd = jnp.repeat(vd, G, axis=2)
-    out = sdpa(q, kd, vd, causal=True, scale=1.0 / math.sqrt(hd),
+    out = sdpa(q, kd, vd, causal=True, scale=1.0 / math.sqrt(cfg.hd),
                kv_len=lens + nv, q_offset=lens)
     y = dense(out.reshape(B, C, cfg.n_heads * cfg.hd), w["wo"])
     return y, {"k": k, "v": v}

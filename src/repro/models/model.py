@@ -414,7 +414,7 @@ def paged_decode_step(cfg: ModelConfig, params, cache: Dict,
     layers: every layer's pool is indexed by the same table); lens (B,)
     int32 per-request cache lengths (write index AND RoPE position).
     The cache pytree mirrors :func:`init_cache`'s structure but each
-    layer leaf is a (P, page, KV, hd) pool — build it with
+    layer leaf is a (P, KV, page, hd) pool — build it with
     ``repro.serve.PagedKVCache``.  Unlike :func:`decode_step` there is
     no batch-wide ``pos``: slots decode at independent offsets, which is
     what lets one compiled step serve ragged in-flight requests.

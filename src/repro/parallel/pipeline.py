@@ -19,7 +19,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 __all__ = ["gpipe_apply", "bubble_fraction"]
 
@@ -71,5 +70,5 @@ def gpipe_apply(stage_fn: Callable, stage_params, x: jax.Array, *,
         return out
 
     in_specs = (jax.tree.map(lambda _: P(axis), stage_params), P())
-    return shard_map(ranked, mesh=mesh, in_specs=in_specs, out_specs=P(),
-                     check_rep=False)(stage_params, x)
+    return jax.shard_map(ranked, mesh=mesh, in_specs=in_specs, out_specs=P(),
+                         check_vma=False)(stage_params, x)

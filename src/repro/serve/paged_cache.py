@@ -3,7 +3,9 @@
 Cache layout
 ------------
 Every attention layer owns two pools ``k``/``v`` of shape
-``(P, page, KV, hd)``: ``P`` physical blocks of ``page`` token rows.  A
+``(P, KV, page, hd)``: ``P`` physical blocks of ``page`` token rows,
+head-major inside a block so each head's page is one contiguous
+``(page, hd)`` slab the TPU kernel can DMA as a tile.  A
 request's cache is the *logical* concatenation of the blocks its row of
 the (B, NB) block table names — the table is shared across layers, so
 one allocation covers the whole model.  ``page`` is the MXU-aligned
@@ -146,7 +148,7 @@ class PagedKVCache:
 
     def _init_pools(self, cfg: ModelConfig) -> Dict:
         dt = cdtype(cfg)
-        shp = (self.n_blocks, self.page, cfg.n_kv_heads, cfg.hd)
+        shp = (self.n_blocks, cfg.n_kv_heads, self.page, cfg.hd)
         first_k, period, n_periods = schedule(cfg)
 
         def pool():
@@ -372,7 +374,7 @@ class PagedKVCache:
         dst = got[0]
 
         def cp(pool):
-            if pool.ndim == 5:          # (n_periods, P, page, KV, hd)
+            if pool.ndim == 5:          # (n_periods, P, KV, page, hd)
                 return pool.at[:, dst].set(pool[:, b])
             return pool.at[dst].set(pool[b])
 

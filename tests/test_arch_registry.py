@@ -125,6 +125,15 @@ def test_new_devices_registered():
     assert {"mi300x", "tpu_v5p"} <= set(list_devices())
 
 
+def test_device_kind_resolves_or_raises():
+    """JAX's device_kind names a catalog spec; an unknown kind is an
+    error, never a silent default device."""
+    from repro.arch import UnknownDeviceError, device_for_kind
+    assert device_for_kind("TPU v5 lite").name == "tpu_v5e"
+    with pytest.raises(UnknownDeviceError, match="TPU v99"):
+        device_for_kind("TPU v99")
+
+
 def test_mi300x_is_a_delta_of_mi300():
     base, x = get_device("mi300"), get_device("mi300x")
     assert set(x.cycle_table) == set(base.cycle_table)

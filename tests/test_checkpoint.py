@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.launch.mesh import make_mesh
 from repro.train.checkpoint import Checkpointer, latest_step, restore, save
 
 
@@ -64,10 +65,10 @@ def test_restore_shape_mismatch_raises(tmp_path):
 def test_elastic_restore_resharding(tmp_path):
     """sharding_fn re-places leaves on the current (1-device) mesh —
     the elastic-restart path."""
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from jax.sharding import NamedSharding, PartitionSpec as P
     t = _tree()
     save(tmp_path, 5, t)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
 
     def sharding_fn(key, arr):
         return NamedSharding(mesh, P(*([None] * arr.ndim)))

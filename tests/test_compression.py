@@ -1,6 +1,7 @@
 """int8 gradient compression: quantisation error bounds, error feedback,
 and the shard_map int8 all-reduce (subprocess with 8 fake devices)."""
 
+import pathlib
 import subprocess
 import sys
 import textwrap
@@ -11,6 +12,8 @@ import numpy as np
 
 from repro.parallel.compression import (compress_decompress, dequantize,
                                         init_residuals, quantize)
+
+_REPO = pathlib.Path(__file__).resolve().parents[1]
 
 
 def test_quantize_bounds():
@@ -54,15 +57,15 @@ def test_int8_psum_multidevice():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import sys; sys.path.insert(0, "src")
         import jax, jax.numpy as jnp, numpy as np
-        from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax.sharding import PartitionSpec as P
+        from repro.launch.mesh import make_mesh
         from repro.parallel.compression import int8_psum
 
-        mesh = jax.make_mesh((8,), ("pod",))
+        mesh = make_mesh((8,), ("pod",))
         x = jax.random.normal(jax.random.PRNGKey(0), (8, 128)) * 2.0
 
-        f = shard_map(lambda a: int8_psum(a, "pod"), mesh=mesh,
-                      in_specs=P("pod"), out_specs=P("pod"))
+        f = jax.shard_map(lambda a: int8_psum(a, "pod"), mesh=mesh,
+                          in_specs=P("pod"), out_specs=P("pod"))
         got = np.asarray(f(x))
         want = np.broadcast_to(np.asarray(x).mean(0, keepdims=True), (8, 128))
         err = np.abs(got - np.repeat(want[:1], 8, 0))
@@ -71,5 +74,5 @@ def test_int8_psum_multidevice():
         print("OK")
     """)
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                         text=True, cwd="/root/repo", timeout=300)
+                         text=True, cwd=_REPO, timeout=300)
     assert "OK" in out.stdout, out.stderr[-2000:]

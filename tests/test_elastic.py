@@ -2,6 +2,7 @@
 8-device production-style mesh with FSDP/TP shardings (subprocess with
 fake devices) — the restart-on-different-cluster-size path."""
 
+import pathlib
 import subprocess
 import sys
 import textwrap
@@ -11,6 +12,8 @@ import jax
 from repro.configs import get_config
 from repro.models import init_params
 from repro.train.checkpoint import save
+
+_REPO = pathlib.Path(__file__).resolve().parents[1]
 
 
 def test_save_one_device_restore_eight(tmp_path):
@@ -26,13 +29,14 @@ def test_save_one_device_restore_eight(tmp_path):
         import jax, numpy as np
         from jax.sharding import NamedSharding
         from repro.configs import get_config
+        from repro.launch.mesh import make_mesh
         from repro.models import init_params
         from repro.models.model import param_axes_rule
         from repro.parallel.api import logical_to_spec
         from repro.train.checkpoint import restore
 
         cfg = get_config("qwen2-7b").reduced()
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         like = jax.eval_shape(lambda k: init_params(cfg, k),
                               jax.random.PRNGKey(0))
 
@@ -58,5 +62,5 @@ def test_save_one_device_restore_eight(tmp_path):
         print("OK", step, len(leaves))
     """)
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                         text=True, cwd="/root/repo", timeout=600)
+                         text=True, cwd=_REPO, timeout=600)
     assert "OK 42" in out.stdout, (out.stdout[-500:], out.stderr[-2000:])

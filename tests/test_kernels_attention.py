@@ -191,8 +191,8 @@ def _paged_case(B, n_prompt_blocks, page, KV, hd, H, dt, seed=11):
     rng = np.random.RandomState(seed)
     P = B * n_prompt_blocks + 1                  # + the null block 0
     q = jnp.asarray(rng.randn(B, H, hd), dt)
-    k_pool = jnp.asarray(rng.randn(P, page, KV, hd), dt)
-    v_pool = jnp.asarray(rng.randn(P, page, KV, hd), dt)
+    k_pool = jnp.asarray(rng.randn(P, KV, page, hd), dt)
+    v_pool = jnp.asarray(rng.randn(P, KV, page, hd), dt)
     perm = rng.permutation(np.arange(1, P))      # blocks land anywhere
     tables = jnp.asarray(perm.reshape(B, n_prompt_blocks), jnp.int32)
     return q, k_pool, v_pool, tables
@@ -211,8 +211,8 @@ def test_paged_decode_vs_contiguous(lens):
                                             jnp.float32)
     kv_len = jnp.asarray(lens, jnp.int32)
     y = ops.paged_decode_attention(q, k_pool, v_pool, tables, kv_len)
-    k = k_pool[tables].reshape(B, NB * page, KV, hd)
-    v = v_pool[tables].reshape(B, NB * page, KV, hd)
+    k = k_pool[tables].transpose(0, 1, 3, 2, 4).reshape(B, NB * page, KV, hd)
+    v = v_pool[tables].transpose(0, 1, 3, 2, 4).reshape(B, NB * page, KV, hd)
     yc = ops.decode_attention(q, k, v, kv_len, block_kv=page)
     np.testing.assert_allclose(np.asarray(y), np.asarray(yc), rtol=1e-5,
                                atol=1e-5)

@@ -74,8 +74,8 @@ def _case(kernel: str, s, dt):
         nb = s["T"] // page
         P = B * nb + 1                       # + the reserved null block
         q = jnp.asarray(RNG.randn(B, s["H"], s["hd"]), dt)
-        k_pool = jnp.asarray(RNG.randn(P, page, s["KV"], s["hd"]), dt)
-        v_pool = jnp.asarray(RNG.randn(P, page, s["KV"], s["hd"]), dt)
+        k_pool = jnp.asarray(RNG.randn(P, s["KV"], page, s["hd"]), dt)
+        v_pool = jnp.asarray(RNG.randn(P, s["KV"], page, s["hd"]), dt)
         # shuffled tables: logical order != physical order, like a real
         # free-list allocation pattern
         perm = RNG.permutation(np.arange(1, P))

@@ -81,7 +81,7 @@ def test_cache_pool_shapes_mirror_init_cache():
     assert len(pc.pools["layers0"]) == first_k
     assert len(pc.pools["layers"]) == period
     k = pc.pools["layers"][0]["k"]
-    assert k.shape == (n_periods, 3, PAGE, CFG.n_kv_heads, CFG.hd)
+    assert k.shape == (n_periods, 3, CFG.n_kv_heads, PAGE, CFG.hd)
 
 
 def test_cache_rejects_non_attention_layers():

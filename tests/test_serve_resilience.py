@@ -152,7 +152,7 @@ def _apply_ops(pc, ops):
     for kind, arg in ops:
         if kind == "alloc":
             ids = pc.alloc(arg)
-            if ids is not None:
+            if ids:                                 # alloc(0) holds nothing
                 held.append(ids)
         elif kind == "free" and held:
             pc.free(held.pop(arg % len(held)))

@@ -3,10 +3,10 @@ uses a (1,1) mesh for plumbing and pure-function checks for the guard),
 plus the op-level shard_assignment/local_shapes contract the sharded
 kernel dispatch plans against."""
 
-import jax
 import pytest
 from jax.sharding import PartitionSpec as P
 
+from repro.launch.mesh import make_mesh
 from repro.parallel.api import (AxisSpec, local_shapes, logical_to_spec,
                                 set_mesh, shard, shard_assignment,
                                 current_mesh)
@@ -66,7 +66,7 @@ def test_no_mesh_is_noop():
 
 
 def test_set_mesh_plumbing():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with set_mesh(mesh):
         assert current_mesh() is mesh
         spec = logical_to_spec((16, 16), ("fsdp", "tp"))

@@ -17,6 +17,7 @@ local shards that genuinely fail the tiling/VMEM contract.
 """
 
 import dataclasses
+import pathlib
 import subprocess
 import sys
 import textwrap
@@ -32,9 +33,11 @@ from repro.models import attention as attn
 from repro.models import moe as moe_mod
 from repro.models import ssm as ssm_mod
 from repro.models.config import ModelConfig, MoESpec, SSMSpec
+from repro.launch.mesh import make_mesh
 from repro.parallel.api import set_mesh
 
 KEY = jax.random.PRNGKey(0)
+_REPO = pathlib.Path(__file__).resolve().parents[1]
 
 _TOL = {"float32": dict(rtol=2e-3, atol=2e-3),
         "bfloat16": dict(rtol=5e-2, atol=5e-2)}
@@ -44,7 +47,7 @@ def _mesh():
     """Largest (data, model) mesh the host supports; (1, 1) on one CPU."""
     n = jax.device_count()
     model = 4 if n % 4 == 0 else (2 if n % 2 == 0 else 1)
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))
 
 
 def _close(got, want, dtype="float32"):
@@ -265,10 +268,11 @@ def test_sharded_parity_8_devices():
         from repro.kernels import dispatch as kdispatch
         from repro.models import attention as attn
         from repro.models.config import ModelConfig
+        from repro.launch.mesh import make_mesh
         from repro.parallel.api import set_mesh
 
         assert jax.device_count() == 8
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = ModelConfig(name="m", family="dense", n_layers=2,
                           d_model=256, n_heads=8, n_kv_heads=4, d_ff=256,
                           vocab_size=512, head_dim=32, dtype="float32")
@@ -290,5 +294,5 @@ def test_sharded_parity_8_devices():
         print("OK")
     """)
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                         text=True, cwd="/root/repo", timeout=600)
+                         text=True, cwd=_REPO, timeout=600)
     assert "OK" in out.stdout, out.stderr[-2000:]
