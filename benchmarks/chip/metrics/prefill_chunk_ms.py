@@ -1,0 +1,18 @@
+"""prefill_chunk_ms: mean device time of one execution of the engine's
+jitted prefill-chunk program (module ``jit__pstep``).  Layer: model
+step."""
+
+from chipbench import trace
+
+PROGRAM = r"^jit__pstep$"
+
+
+def read(ctx):
+    if ctx.trace is None:
+        return None
+    t = trace.program_times(ctx.trace, PROGRAM)
+    if not len(t):
+        return None
+    ctx.note(f"prefill_chunk_ms: {len(t)} executions, median "
+             f"{1e3 * float(sorted(t)[len(t) // 2]):.4f} ms")
+    return 1e3 * float(t.mean())
