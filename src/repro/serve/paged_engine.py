@@ -71,6 +71,21 @@ Temperature sampling uses per-request key streams
 (``fold_in(PRNGKey(seed), request_index)``, split once per sampled
 token); a preempted request's recompute replays the same stream from
 the start, so sampled runs are preemption-deterministic too.
+
+Spans: every tick records ``jax.profiler.TraceAnnotation`` spans, which
+cost about a microsecond each when no profiler runs and otherwise land
+in the profiler's trace on the device's clock.  Their integer arguments
+are the tick's counts::
+
+    serve.tick             tick, queued, busy (slots held)
+      serve.control        steps 1-4: cancelled, timed_out, shed
+      serve.admit          step 5: admitted, prefix_blocks
+      serve.prefill        one chunk of step 6: req, slot, start, n_valid
+        serve.prefill.wait   the wait for that chunk
+      serve.grow           step 7a: grown, preempted
+      serve.decode         step 7b: active (slots), kv_rows (sum of lens + 1)
+        serve.decode.wait    the wait for that step
+      serve.check          the invariant checks, when on
 """
 
 from __future__ import annotations
@@ -94,6 +109,8 @@ from repro.serve.resilience import (CANCELLED, OK, PREEMPTED, SHED, TIMEOUT,
                                     QueueCapPolicy, queue_entries)
 
 __all__ = ["PagedServeEngine", "Request", "RequestResult"]
+
+span = jax.profiler.TraceAnnotation          # see "Spans" above
 
 
 @dataclasses.dataclass
@@ -372,253 +389,296 @@ class PagedServeEngine:
                     f"{len(queue)} queued and "
                     f"{sum(s is not None for s in slots)} in-flight "
                     "requests — deadlock canary tripped")
+            with span("serve.tick", tick=tick, queued=len(queue),
+                      busy=B - slots.count(None)):
+                stalled = False
+                with span("serve.control") as sp:
+                    counts0 = (n_cancel, n_timeout, n_shed)
+                    # 1. faults: release expired seizures, then seize for
+                    # faults firing now (seizing is a real alloc, so
+                    # conservation holds)
+                    if fault_plan is not None:
+                        keep = []
+                        for release, ids in seized:
+                            if release <= tick:
+                                self.cache.free(ids)
+                            else:
+                                keep.append((release, ids))
+                        seized = keep
+                        for f in fault_plan.seizures(tick):
+                            k = self.cache.free_blocks if f.n is None \
+                                else min(f.n, self.cache.free_blocks)
+                            ids = self.cache.alloc(k) or []
+                            if ids:
+                                seized.append((tick + f.duration, ids))
+                        stalled = fault_plan.stalled(tick)
+                        if stalled:
+                            n_stalled += 1
 
-            # 1. faults: release expired seizures, then seize for faults
-            # firing now (seizing is a real alloc, so conservation holds)
-            stalled = False
-            if fault_plan is not None:
-                keep = []
-                for release, ids in seized:
-                    if release <= tick:
-                        self.cache.free(ids)
-                    else:
-                        keep.append((release, ids))
-                seized = keep
-                for f in fault_plan.seizures(tick):
-                    k = self.cache.free_blocks if f.n is None \
-                        else min(f.n, self.cache.free_blocks)
-                    ids = self.cache.alloc(k) or []
-                    if ids:
-                        seized.append((tick + f.duration, ids))
-                stalled = fault_plan.stalled(tick)
-                if stalled:
-                    n_stalled += 1
-
-            # 2. cancellations, then 3. timeouts — queued or in-flight,
-            # partial tokens kept, blocks released refcount-exactly
-            cancelled = [rid for rid in queue
-                         if reqs[rid].cancel_at is not None
-                         and tick >= reqs[rid].cancel_at]
-            drop_queued(cancelled, CANCELLED,
-                        lambda rid: f"cancelled at tick "
-                                    f"{reqs[rid].cancel_at} while queued")
-            n_cancel += len(cancelled)
-            for si in range(B):
-                slot = slots[si]
-                if slot is None:
-                    continue
-                r = reqs[slot.req]
-                if r.cancel_at is not None and tick >= r.cancel_at:
-                    retire(si, CANCELLED,
-                           f"cancelled at tick {r.cancel_at} in flight")
-                    n_cancel += 1
-            timed_out = [rid for rid in queue
-                         if reqs[rid].deadline is not None
-                         and tick > reqs[rid].deadline]
-            drop_queued(timed_out, TIMEOUT,
-                        lambda rid: f"deadline {reqs[rid].deadline} passed "
-                                    "while queued")
-            n_timeout += len(timed_out)
-            for si in range(B):
-                slot = slots[si]
-                if slot is None:
-                    continue
-                r = reqs[slot.req]
-                if r.deadline is not None and tick > r.deadline:
-                    retire(si, TIMEOUT,
-                           f"deadline {r.deadline} passed with "
-                           f"{slot.remaining} tokens still to emit")
-                    n_timeout += 1
-
-            # 3b. fault-forced preemptions (same victim rule as organic)
-            if fault_plan is not None:
-                for si in victims_latest_first()[
-                        :fault_plan.forced_preemptions(tick)]:
-                    preempt(si, "forced by fault plan")
-
-            # 4. shed: queue-cap bound first, then the pluggable policy
-            if self.policies:
-                for policy in self.policies:
-                    waiting = [rid for rid in queue
-                               if reqs[rid].arrival <= tick]
-                    if not waiting:
-                        break
-                    entries = queue_entries(tick, waiting, reqs,
-                                            self.prefill_chunk)
-                    verdicts = dict(policy.shed(tick, entries))
-                    drop_queued(list(verdicts), SHED, verdicts.__getitem__)
-                    n_shed += len(verdicts)
-
-            # 5. admit: FIFO while a slot and the PROMPT reservation fit
-            # (decode blocks grow lazily); a stalled tick admits nothing
-            while not stalled and queue \
-                    and reqs[queue[0]].arrival <= tick:
-                free_slots = [i for i, s in enumerate(slots) if s is None]
-                if not free_slots:
-                    break
-                rid = queue[0]
-                r = reqs[rid]
-                s = r.prompt.shape[0]
-                need = self._prompt_blocks(s)
-                matched: List[int] = []
-                if self.prefix_cache:
-                    # cap: >= 1 suffix token must prefill (first-token
-                    # logits), which also keeps every later write past
-                    # the shared pages — see the module docstring
-                    matched = self.cache.match_prefix(
-                        r.prompt)[:(s - 1) // self.page]
-                    self.cache.acquire(matched)
-                ids = self.cache.alloc(need - len(matched))
-                if ids is None:
-                    if matched:
-                        self.cache.free(matched)    # drop the hold, wait
-                    break                           # wait for retirements
-                queue.popleft()
-                si = free_slots[0]
-                admitted_at[rid] = tick
-                admit_time[rid] = time.perf_counter()
-                prefix_blocks[rid] = len(matched)
-                blocks_reused += len(matched)
-                blocks_needed += (s - 1) // self.page
-                slots[si] = _Slot(req=rid, ids=matched + ids,
-                                  remaining=r.n_steps,
-                                  key=jax.random.fold_in(root, rid),
-                                  filled=len(matched) * self.page,
-                                  registered=len(matched),
-                                  seq=seq_counter)
-                seq_counter += 1
-                tables[si, :] = 0
-                tables[si, :len(slots[si].ids)] = slots[si].ids
-                lens[si] = 0                        # ACTIVE only after prefill
-
-            occupancy.append(self.cache.occupancy())
-
-            # 6. prefill: one chunk per PREFILLING slot, then decode below
-            # — long prompts stall a tick by at most one chunk of compute
-            C = self.prefill_chunk
-            for si in range(B):
-                slot = slots[si]
-                if stalled or slot is None or lens[si] > 0:
-                    continue
-                r = reqs[slot.req]
-                s = r.prompt.shape[0]
-                pos = slot.filled
-                nv = min(C, s - pos)
-                toks = np.zeros((1, C), np.int32)
-                toks[0, :nv] = r.prompt[pos:pos + nv]
-                # jnp.array (not asarray): don't alias scheduler state the
-                # async dispatch would race with (same rationale as decode)
-                logits, greedy, pools = self._prefill(
-                    self.params, pools, jnp.array(toks),
-                    jnp.array(tables[si:si + 1]),
-                    jnp.array([pos], np.int32), jnp.array([nv], np.int32))
-                jax.block_until_ready((logits, greedy, pools))
-                prefill_chunks += 1
-                slot.filled = pos + nv
-                if self.prefix_cache:
-                    full = slot.filled // self.page
-                    if full > slot.registered:
-                        self.cache.register_prefix(
-                            r.prompt[:full * self.page], slot.ids[:full])
-                        slot.registered = full
-                if slot.filled == s:                # prefill done -> ACTIVE
-                    if temperature <= 0.0:
-                        tok = int(greedy[0])
-                    else:
-                        slot.key, sub = jax.random.split(slot.key)
-                        tok = self._sample(logits[0, -1], sub, temperature)
-                    lens[si] = s
-                    pend[si] = tok
-                    emit(slot.req, tok)
-                    slot.remaining -= 1
-                    if slot.remaining == 0:
-                        retire(si)
-
-            # 7a. grow: each ACTIVE slot writing into a fresh page this
-            # tick allocates its next block; exhaustion preempts victims
-            # latest-admitted first (possibly the grower itself) instead
-            # of deadlocking the tick
-            for si in range(B):
-                if stalled:
-                    break
-                slot = slots[si]
-                if slot is None or lens[si] == 0:
-                    continue
-                if int(lens[si]) < len(slot.ids) * self.page:
-                    continue                        # page not full yet
-                got = self.cache.alloc(1)
-                if got is None:
-                    for vi in victims_latest_first():
-                        victim_is_self = vi == si
-                        preempt(vi, "pool exhausted growing slot "
-                                    f"{si} at length {int(lens[si])}")
-                        if victim_is_self:
-                            break
-                        got = self.cache.alloc(1)
-                        if got is not None:
-                            break
-                if got is None or slots[si] is None:
-                    continue                        # grower was evicted
-                slot.ids.append(got[0])
-                tables[si, len(slot.ids) - 1] = got[0]
-
-            active = [] if stalled else \
-                [i for i, sl in enumerate(slots)
-                 if sl is not None and lens[i] > 0]
-            if active:
-                # jnp.array (not asarray): asarray zero-copies numpy on CPU,
-                # so the async decode would alias these host buffers while
-                # the scheduler keeps mutating them (retire zeroes table
-                # rows, lens advance) — a read/write race on real state.
-                # PREFILLING slots already sit at lens 0 so the decode
-                # masks them like idle slots; their table rows are real
-                # but every read is kv_len-masked and the pend-0 write
-                # lands at row 0 of their first block, which the next
-                # chunk overwrites (positions are absolute).
-                dec_tables = tables.copy()
-                for si in range(B):
-                    if slots[si] is not None and lens[si] == 0:
-                        dec_tables[si] = 0          # scatter to null block
-                logits, greedy, pools = self._decode(
-                    self.params, pools, jnp.array(pend[:, None]),
-                    jnp.array(dec_tables), jnp.array(lens))
-                # materialize the whole tick before dispatching anything
-                # else: overlapping executions on XLA:CPU's shared thunk
-                # thread pool perturb parallel-reduction numerics, and a
-                # near-tie argmax flip breaks bitwise greedy parity with
-                # the synchronous engine (whose single lax.scan decode
-                # loop never overlaps itself).  The greedy-token transfer
-                # below already serialized most of the tick; this pins
-                # the pool updates too, so no computation from run() is
-                # ever still in flight when the caller's next one starts.
-                jax.block_until_ready((logits, greedy, pools))
-                decode_steps += 1
-                lens[active] += 1
-                keys = None
-                if temperature > 0.0:
-                    keys = []
-                    active_set = set(active)
+                    # 2. cancellations, then 3. timeouts — queued or
+                    # in-flight, partial tokens kept, blocks released
+                    # refcount-exactly
+                    cancelled = [rid for rid in queue
+                                 if reqs[rid].cancel_at is not None
+                                 and tick >= reqs[rid].cancel_at]
+                    drop_queued(cancelled, CANCELLED,
+                                lambda rid: f"cancelled at tick "
+                                            f"{reqs[rid].cancel_at} while "
+                                            "queued")
+                    n_cancel += len(cancelled)
                     for si in range(B):
-                        if si in active_set:
-                            slots[si].key, sub = jax.random.split(
-                                slots[si].key)
-                            keys.append(sub)
-                        else:
-                            keys.append(root)     # idle slot: discarded
-                toks = self._sample_tick(logits[:, -1], greedy, keys,
-                                         temperature)
-                for si in active:
+                        slot = slots[si]
+                        if slot is None:
+                            continue
+                        r = reqs[slot.req]
+                        if r.cancel_at is not None and tick >= r.cancel_at:
+                            retire(si, CANCELLED,
+                                   f"cancelled at tick {r.cancel_at} in "
+                                   "flight")
+                            n_cancel += 1
+                    timed_out = [rid for rid in queue
+                                 if reqs[rid].deadline is not None
+                                 and tick > reqs[rid].deadline]
+                    drop_queued(timed_out, TIMEOUT,
+                                lambda rid: f"deadline {reqs[rid].deadline} "
+                                            "passed while queued")
+                    n_timeout += len(timed_out)
+                    for si in range(B):
+                        slot = slots[si]
+                        if slot is None:
+                            continue
+                        r = reqs[slot.req]
+                        if r.deadline is not None and tick > r.deadline:
+                            retire(si, TIMEOUT,
+                                   f"deadline {r.deadline} passed with "
+                                   f"{slot.remaining} tokens still to emit")
+                            n_timeout += 1
+
+                    # 3b. fault-forced preemptions (same victim rule as
+                    # organic)
+                    if fault_plan is not None:
+                        for si in victims_latest_first()[
+                                :fault_plan.forced_preemptions(tick)]:
+                            preempt(si, "forced by fault plan")
+
+                    # 4. shed: queue-cap bound first, then the pluggable
+                    # policy
+                    if self.policies:
+                        for policy in self.policies:
+                            waiting = [rid for rid in queue
+                                       if reqs[rid].arrival <= tick]
+                            if not waiting:
+                                break
+                            entries = queue_entries(tick, waiting, reqs,
+                                                    self.prefill_chunk)
+                            verdicts = dict(policy.shed(tick, entries))
+                            drop_queued(list(verdicts), SHED,
+                                        verdicts.__getitem__)
+                            n_shed += len(verdicts)
+                    sp.set_metadata(cancelled=n_cancel - counts0[0],
+                                    timed_out=n_timeout - counts0[1],
+                                    shed=n_shed - counts0[2])
+
+                # 5. admit: FIFO while a slot and the PROMPT reservation
+                # fit (decode blocks grow lazily); a stalled tick admits
+                # nothing
+                with span("serve.admit") as sp:
+                    counts0 = (seq_counter, blocks_reused)
+                    while not stalled and queue \
+                            and reqs[queue[0]].arrival <= tick:
+                        free_slots = [i for i, s in enumerate(slots)
+                                      if s is None]
+                        if not free_slots:
+                            break
+                        rid = queue[0]
+                        r = reqs[rid]
+                        s = r.prompt.shape[0]
+                        need = self._prompt_blocks(s)
+                        matched: List[int] = []
+                        if self.prefix_cache:
+                            # cap: >= 1 suffix token must prefill
+                            # (first-token logits), which also keeps every
+                            # later write past the shared pages — see the
+                            # module docstring
+                            matched = self.cache.match_prefix(
+                                r.prompt)[:(s - 1) // self.page]
+                            self.cache.acquire(matched)
+                        ids = self.cache.alloc(need - len(matched))
+                        if ids is None:
+                            if matched:
+                                self.cache.free(matched)  # drop hold, wait
+                            break                 # wait for retirements
+                        queue.popleft()
+                        si = free_slots[0]
+                        admitted_at[rid] = tick
+                        admit_time[rid] = time.perf_counter()
+                        prefix_blocks[rid] = len(matched)
+                        blocks_reused += len(matched)
+                        blocks_needed += (s - 1) // self.page
+                        slots[si] = _Slot(req=rid, ids=matched + ids,
+                                          remaining=r.n_steps,
+                                          key=jax.random.fold_in(root, rid),
+                                          filled=len(matched) * self.page,
+                                          registered=len(matched),
+                                          seq=seq_counter)
+                        seq_counter += 1
+                        tables[si, :] = 0
+                        tables[si, :len(slots[si].ids)] = slots[si].ids
+                        lens[si] = 0              # ACTIVE only after prefill
+                    sp.set_metadata(admitted=seq_counter - counts0[0],
+                                    prefix_blocks=blocks_reused - counts0[1])
+
+                occupancy.append(self.cache.occupancy())
+
+                # 6. prefill: one chunk per PREFILLING slot, then decode
+                # below — long prompts stall a tick by at most one chunk of
+                # compute
+                C = self.prefill_chunk
+                for si in range(B):
                     slot = slots[si]
-                    tok = int(toks[si])
-                    pend[si] = tok
-                    emit(slot.req, tok)
-                    slot.remaining -= 1
-                    if slot.remaining == 0:
-                        retire(si)
-            tick += 1
-            if checking:
-                self.cache.check_invariants()
-                self._assert_refcount_exact(slots, seized)
+                    if stalled or slot is None or lens[si] > 0:
+                        continue
+                    r = reqs[slot.req]
+                    s = r.prompt.shape[0]
+                    pos = slot.filled
+                    nv = min(C, s - pos)
+                    with span("serve.prefill", req=slot.req, slot=si,
+                              start=pos, n_valid=nv):
+                        toks = np.zeros((1, C), np.int32)
+                        toks[0, :nv] = r.prompt[pos:pos + nv]
+                        # jnp.array (not asarray): don't alias scheduler
+                        # state the async dispatch would race with (same
+                        # rationale as decode)
+                        logits, greedy, pools = self._prefill(
+                            self.params, pools, jnp.array(toks),
+                            jnp.array(tables[si:si + 1]),
+                            jnp.array([pos], np.int32),
+                            jnp.array([nv], np.int32))
+                        with span("serve.prefill.wait"):
+                            jax.block_until_ready((logits, greedy, pools))
+                        prefill_chunks += 1
+                        slot.filled = pos + nv
+                        if self.prefix_cache:
+                            full = slot.filled // self.page
+                            if full > slot.registered:
+                                self.cache.register_prefix(
+                                    r.prompt[:full * self.page],
+                                    slot.ids[:full])
+                                slot.registered = full
+                        if slot.filled == s:        # prefill done -> ACTIVE
+                            if temperature <= 0.0:
+                                tok = int(greedy[0])
+                            else:
+                                slot.key, sub = jax.random.split(slot.key)
+                                tok = self._sample(logits[0, -1], sub,
+                                                   temperature)
+                            lens[si] = s
+                            pend[si] = tok
+                            emit(slot.req, tok)
+                            slot.remaining -= 1
+                            if slot.remaining == 0:
+                                retire(si)
+
+                # 7a. grow: each ACTIVE slot writing into a fresh page this
+                # tick allocates its next block; exhaustion preempts
+                # victims latest-admitted first (possibly the grower
+                # itself) instead of deadlocking the tick
+                with span("serve.grow") as sp:
+                    grown, preempts0 = 0, n_preempt
+                    for si in range(B):
+                        if stalled:
+                            break
+                        slot = slots[si]
+                        if slot is None or lens[si] == 0:
+                            continue
+                        if int(lens[si]) < len(slot.ids) * self.page:
+                            continue                # page not full yet
+                        got = self.cache.alloc(1)
+                        if got is None:
+                            for vi in victims_latest_first():
+                                victim_is_self = vi == si
+                                preempt(vi, "pool exhausted growing slot "
+                                            f"{si} at length {int(lens[si])}")
+                                if victim_is_self:
+                                    break
+                                got = self.cache.alloc(1)
+                                if got is not None:
+                                    break
+                        if got is None or slots[si] is None:
+                            continue                # grower was evicted
+                        slot.ids.append(got[0])
+                        tables[si, len(slot.ids) - 1] = got[0]
+                        grown += 1
+                    sp.set_metadata(grown=grown,
+                                    preempted=n_preempt - preempts0)
+
+                active = [] if stalled else \
+                    [i for i, sl in enumerate(slots)
+                     if sl is not None and lens[i] > 0]
+                # every slot outside ``active`` sits at length 0, so the
+                # rows the step attends, sum(lens[active] + 1), is one sum
+                if active:
+                    with span("serve.decode", active=len(active),
+                              kv_rows=int(lens.sum()) + len(active)):
+                        # jnp.array (not asarray): asarray zero-copies
+                        # numpy on CPU, so the async decode would alias
+                        # these host buffers while the scheduler keeps
+                        # mutating them (retire zeroes table rows, lens
+                        # advance) — a read/write race on real state.
+                        # PREFILLING slots already sit at lens 0 so the
+                        # decode masks them like idle slots; their table
+                        # rows are real but every read is kv_len-masked and
+                        # the pend-0 write lands at row 0 of their first
+                        # block, which the next chunk overwrites (positions
+                        # are absolute).
+                        dec_tables = tables.copy()
+                        for si in range(B):
+                            if slots[si] is not None and lens[si] == 0:
+                                dec_tables[si] = 0  # scatter to null block
+                        logits, greedy, pools = self._decode(
+                            self.params, pools, jnp.array(pend[:, None]),
+                            jnp.array(dec_tables), jnp.array(lens))
+                        # materialize the whole tick before dispatching
+                        # anything else: overlapping executions on
+                        # XLA:CPU's shared thunk thread pool perturb
+                        # parallel-reduction numerics, and a near-tie
+                        # argmax flip breaks bitwise greedy parity with the
+                        # synchronous engine (whose single lax.scan decode
+                        # loop never overlaps itself).  The greedy-token
+                        # transfer below already serialized most of the
+                        # tick; this pins the pool updates too, so no
+                        # computation from run() is ever still in flight
+                        # when the caller's next one starts.
+                        with span("serve.decode.wait"):
+                            jax.block_until_ready((logits, greedy, pools))
+                        decode_steps += 1
+                        lens[active] += 1
+                        keys = None
+                        if temperature > 0.0:
+                            keys = []
+                            active_set = set(active)
+                            for si in range(B):
+                                if si in active_set:
+                                    slots[si].key, sub = jax.random.split(
+                                        slots[si].key)
+                                    keys.append(sub)
+                                else:
+                                    keys.append(root)  # idle: discarded
+                        toks = self._sample_tick(logits[:, -1], greedy,
+                                                 keys, temperature)
+                        for si in active:
+                            slot = slots[si]
+                            tok = int(toks[si])
+                            pend[si] = tok
+                            emit(slot.req, tok)
+                            slot.remaining -= 1
+                            if slot.remaining == 0:
+                                retire(si)
+                tick += 1
+                if checking:
+                    with span("serve.check"):
+                        self.cache.check_invariants()
+                        self._assert_refcount_exact(slots, seized)
 
         # the run can end inside a seizure window (every request already
         # terminal); hand the fault-held blocks back so the pool drains
