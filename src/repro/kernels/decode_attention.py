@@ -15,12 +15,16 @@ Two variants share one kernel body:
   fixed-size blocks ``(P, KV, block_kv, hd)`` — head-major inside a
   block, so one head's page is a contiguous, (8, 128)-tileable
   ``(block_kv, hd)`` slab; each request names its blocks via a
-  ``(B, NB)`` block table.  The table and the per-request
-  lengths ride in as scalar-prefetch operands
+  ``(B, NB)`` block table.  The serve engine stacks the pools of its
+  scanned layers into one ``(L, P, KV, block_kv, hd)`` buffer, and the
+  kernel reads that stack in place: the table, the per-request lengths
+  and the layer index ride in as scalar-prefetch operands
   (``compat.prefetch_grid_spec``), so the K/V BlockSpec index maps
-  gather ``pool[table[b, j]]`` per grid step — the same ``kv_len`` mask
-  machinery handles the partial last block, and fully-masked blocks are
-  skipped by ``pl.when`` exactly like the contiguous variant.
+  gather ``pool[layer, table[b, j]]`` per grid step — the same
+  ``kv_len`` mask machinery handles the partial last block, and
+  fully-masked blocks are skipped by ``pl.when`` exactly like the
+  contiguous variant.  A single ``(P, KV, block_kv, hd)`` pool is the
+  stack with a unit layer axis.
 """
 
 from __future__ import annotations
@@ -146,11 +150,12 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return out.reshape(B, KV, G, hd).reshape(B, H, hd)
 
 
-def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                         m_ref, l_ref, acc_ref, *, scale: float, n_kv: int,
-                         block_kv: int, kv_heads: int):
-    # tbl_ref/len_ref are the scalar-prefetch operands; the K/V gather
-    # already happened in the BlockSpec index maps below.
+def _paged_decode_kernel(tbl_ref, len_ref, layer_ref, q_ref, k_ref, v_ref,
+                         o_ref, m_ref, l_ref, acc_ref, *, scale: float,
+                         n_kv: int, block_kv: int, kv_heads: int):
+    # tbl_ref/len_ref/layer_ref are the scalar-prefetch operands; the K/V
+    # gather already happened in the BlockSpec index maps below.
+    del tbl_ref, layer_ref
     kv_len = len_ref[pl.program_id(0) // kv_heads]
     _decode_body(kv_len, pl.program_id(1), q_ref, k_ref, v_ref, o_ref,
                  m_ref, l_ref, acc_ref, scale=scale, n_kv=n_kv,
@@ -160,21 +165,28 @@ def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
                            v_pool: jax.Array, block_tables: jax.Array,
-                           kv_len: jax.Array, *,
+                           kv_len: jax.Array, layer=None, *,
                            interpret: bool = False) -> jax.Array:
-    """q: (B, H, hd); k_pool/v_pool: (P, KV, block_kv, hd);
+    """q: (B, H, hd); k_pool/v_pool: a (L, P, KV, block_kv, hd) stack of
+    layer pools with ``layer`` the int32 index of the one to read, or a
+    single (P, KV, block_kv, hd) pool with ``layer=None``;
     block_tables: (B, NB) int32 physical block ids; kv_len: (B,) int32.
 
     Each request attends its first ``kv_len[b]`` cache positions, read
-    from pool blocks ``block_tables[b, 0..ceil(kv_len/block_kv))`` — the
-    page size IS the kv tile, so it must be MXU-aligned (the
-    ``paged_decode_attention`` planner chooses it).  Table slots past a
-    request's written prefix must hold valid (in-range) block ids — the
-    serve engine points them at its reserved null block — because the
-    gather runs before the ``pl.when`` mask skips the compute.
+    from pool blocks ``block_tables[b, 0..ceil(kv_len/block_kv))`` of
+    layer ``layer`` — the page size IS the kv tile, so it must be
+    MXU-aligned (the ``paged_decode_attention`` planner chooses it).
+    The stack is read in place: only the tabled pages of one layer move.
+    Table slots past a request's written prefix must hold valid
+    (in-range) block ids — the serve engine points them at its reserved
+    null block — because the gather runs before the ``pl.when`` mask
+    skips the compute.
     """
+    if layer is None:
+        # one pool is the stack with a unit layer axis (a bitcast)
+        k_pool, v_pool, layer = k_pool[None], v_pool[None], 0
     B, H, hd = q.shape
-    KV, block_kv = k_pool.shape[1], k_pool.shape[2]
+    KV, block_kv = k_pool.shape[2], k_pool.shape[3]
     NB = block_tables.shape[1]
     G = H // KV
     T = NB * block_kv
@@ -185,22 +197,24 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     qf = q.reshape(B, KV, G, hd).reshape(B * KV, G, hd)
     tables = jnp.asarray(block_tables, jnp.int32)
     lens = _lens_vector(kv_len, B)
+    layers = jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
 
-    def _kv_index(i, j, tbl_ref, len_ref):
+    def _kv_index(i, j, tbl_ref, len_ref, layer_ref):
         # gather: grid step (i, j) reads kv head i % KV of physical block
-        # table[b, j] — one contiguous (block_kv, hd) page
+        # table[b, j] of the layer's pool — one contiguous (block_kv, hd)
+        # page
         del len_ref
-        return (tbl_ref[i // KV, j], i % KV, 0, 0)
+        return (layer_ref[0], tbl_ref[i // KV, j], i % KV, 0, 0)
 
     grid_spec = compat.prefetch_grid_spec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B * KV, NB),
         in_specs=[
-            pl.BlockSpec((1, G, hd), lambda i, j, t, n: (i, 0, 0)),
-            pl.BlockSpec((1, None, block_kv, hd), _kv_index),
-            pl.BlockSpec((1, None, block_kv, hd), _kv_index),
+            pl.BlockSpec((1, G, hd), lambda i, j, t, n, y: (i, 0, 0)),
+            pl.BlockSpec((None, 1, None, block_kv, hd), _kv_index),
+            pl.BlockSpec((None, 1, None, block_kv, hd), _kv_index),
         ],
-        out_specs=pl.BlockSpec((1, G, hd), lambda i, j, t, n: (i, 0, 0)),
+        out_specs=pl.BlockSpec((1, G, hd), lambda i, j, t, n, y: (i, 0, 0)),
         scratch_shapes=[
             compat.vmem((G, 1), jnp.float32),
             compat.vmem((G, 1), jnp.float32),
@@ -216,5 +230,5 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
         compiler_params=compat.tpu_compiler_params(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(tables, lens, qf, k_pool, v_pool)
+    )(tables, lens, layers, qf, k_pool, v_pool)
     return out.reshape(B, KV, G, hd).reshape(B, H, hd)

@@ -219,21 +219,24 @@ def decode_attention(q, k, v, kv_len, *, device=None,
                                 interpret=compat.resolve_interpret(interpret))
 
 
-def paged_decode_attention(q, k_pool, v_pool, block_tables, kv_len, *,
-                           device=None, plan: Optional[TilePlan] = None,
+def paged_decode_attention(q, k_pool, v_pool, block_tables, kv_len,
+                           layer=None, *, device=None,
+                           plan: Optional[TilePlan] = None,
                            block_kv: Optional[int] = None,
                            interpret: Optional[bool] = None):
     """Flash-decode over a block-paged KV pool.
 
-    q (B, H, hd); k_pool/v_pool (P, KV, page, hd); block_tables (B, NB)
-    int32 physical block ids; kv_len (B,) int32 per-request lengths.
+    q (B, H, hd); k_pool/v_pool a (L, P, KV, page, hd) stack of layer
+    pools read at int32 index ``layer``, or one (P, KV, page, hd) pool
+    with ``layer=None``; block_tables (B, NB) int32 physical block ids;
+    kv_len (B,) int32 per-request lengths.
     The pool's page size IS the kv tile, so the plan's ``block_kv`` must
     equal it — the ``shapes["page"]`` pin makes the planner agree on
     every device; there is no ``pad=`` mode (pool geometry is aligned by
     construction via :class:`~repro.serve.PagedKVCache`).
     """
     B, H, hd = q.shape
-    KV, page = k_pool.shape[1], k_pool.shape[2]
+    KV, page = k_pool.shape[-3], k_pool.shape[-2]
     NB = block_tables.shape[1]
     plan, blocks = _resolve("paged_decode_attention", plan,
                             {"B": B, "T": NB * page, "H": H, "KV": KV,
@@ -246,7 +249,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, kv_len, *,
             "plan with shapes['page'] (or block_kv=) pinned to the pool's "
             "page so the gather granularity matches")
     return _da.paged_decode_attention(
-        q, k_pool, v_pool, block_tables, kv_len,
+        q, k_pool, v_pool, block_tables, kv_len, layer,
         interpret=compat.resolve_interpret(interpret))
 
 

@@ -371,18 +371,39 @@ def attn_decode(cfg: ModelConfig, w, x: jax.Array, cache: Dict,
     return y, {"k": k, "v": v}
 
 
-def _gather_pages(pool: jax.Array, tables: jax.Array) -> jax.Array:
-    """(P, KV, page, hd) pool + (B, NB) tables -> the dense (B, NB*page,
-    KV, hd) cache holding each request's blocks in table order."""
-    B, KV, hd = tables.shape[0], pool.shape[1], pool.shape[3]
-    return pool[tables].transpose(0, 1, 3, 2, 4).reshape(B, -1, KV, hd)
+def _layer_pools(cache: Dict, layer) -> Tuple[jax.Array, jax.Array,
+                                                jax.Array]:
+    """(k, v, layer) as a stack of layer pools and the index to use: a
+    (L, P, KV, page, hd) stack is used as it is; a single (P, KV, page,
+    hd) pool (``layer=None``) is the stack with a unit layer axis."""
+    if layer is None:
+        return cache["k"][None], cache["v"][None], jnp.int32(0)
+    return cache["k"], cache["v"], jnp.asarray(layer, jnp.int32)
 
 
-def _paged_attention_kernel(q, k_pool, v_pool, tables, kv_len, *,
+def _unstack(k: jax.Array, v: jax.Array, layer) -> Dict:
+    """The cache leaves in the caller's shape (see :func:`_layer_pools`)."""
+    return {"k": k, "v": v} if layer is not None else {"k": k[0], "v": v[0]}
+
+
+def _gather_pages(pool: jax.Array, tables: jax.Array,
+                  layer: jax.Array) -> jax.Array:
+    """(L, P, KV, page, hd) pool stack + (B, NB) tables -> layer
+    ``layer``'s dense (B, NB*page, KV, hd) cache holding each request's
+    blocks in table order (only the tabled blocks are read)."""
+    B, KV, hd = tables.shape[0], pool.shape[2], pool.shape[4]
+    # one (page, hd) slab per index: with whole (KV, page, hd) blocks the
+    # TPU compiler may split the gather by slicing the whole stack
+    head = jnp.arange(KV, dtype=jnp.int32)
+    pages = pool[layer, tables[..., None], head]       # (B, NB, KV, page, hd)
+    return pages.transpose(0, 1, 3, 2, 4).reshape(B, -1, KV, hd)
+
+
+def _paged_attention_kernel(q, k_pool, v_pool, tables, kv_len, layer, *,
                             device=None):
     """Try the paged Pallas kernel; ``None`` means "gather + reference"."""
     B, S, H, hd = q.shape
-    KV, page = k_pool.shape[1], k_pool.shape[2]
+    KV, page = k_pool.shape[-3], k_pool.shape[-2]
     NB = tables.shape[1]
     dec = kdispatch.decide(
         "paged_decode_attention",
@@ -391,61 +412,70 @@ def _paged_attention_kernel(q, k_pool, v_pool, tables, kv_len, *,
     if not dec.use_kernel:
         return None
     return kops.paged_decode_attention(q[:, 0], k_pool, v_pool, tables,
-                                       kv_len, plan=dec.plan)[:, None]
+                                       kv_len, layer, plan=dec.plan)[:, None]
 
 
 def attn_decode_paged(cfg: ModelConfig, w, x: jax.Array, cache: Dict,
-                      block_tables: jax.Array,
-                      lens: jax.Array) -> Tuple[jax.Array, Dict]:
+                      block_tables: jax.Array, lens: jax.Array,
+                      layer=None) -> Tuple[jax.Array, Dict]:
     """One continuous-batching decode step against the shared KV pool.
 
     x: (B, 1, D) — each row is a *different* request's pending token;
-    cache ``{"k", "v"}``: (P, KV, page, hd) block pools; block_tables:
-    (B, NB) int32 physical block ids (unused tail slots must point at the
-    engine's reserved null block 0); lens: (B,) int32 tokens already in
-    each request's cache — both the new token's write position and its
-    RoPE position.  Unlike :func:`attn_decode` there is no per-batch
-    ``pos`` scalar: every request sits at its own offset.
+    cache ``{"k", "v"}``: the (L, P, KV, page, hd) stacks of the scanned
+    layers' block pools, of which this layer's is ``layer`` (an int32
+    scalar), or with ``layer=None`` one layer's (P, KV, page, hd) pools;
+    block_tables: (B, NB) int32 physical block ids (unused tail slots
+    must point at the engine's reserved null block 0); lens: (B,) int32
+    tokens already in each request's cache — both the new token's write
+    position and its RoPE position.  Unlike :func:`attn_decode` there is
+    no per-batch ``pos`` scalar: every request sits at its own offset.
+    The new rows are written into the stack and attention reads it
+    through the layer index, so no layer's pool is sliced out or copied:
+    inside a layer scan that carries the stack, the write is in place.
     """
     B, S, D = x.shape
     lens = jnp.asarray(lens, jnp.int32)
     q, k_new, v_new = _qkv(cfg, w, x, lens[:, None])
-    page = cache["k"].shape[2]
+    k, v, li = _layer_pools(cache, layer)
+    page = k.shape[3]
     tables = jnp.asarray(block_tables, jnp.int32)
     # scatter the new K/V row into pool block table[b, lens//page] at
     # row lens%page — requests own disjoint blocks, so rows never collide
     # (idle engine slots all hit the null block, whose content is never
-    # attended unmasked)
-    slot = jnp.take_along_axis(tables, (lens // page)[:, None], axis=1)[:, 0]
-    row = lens % page
-    k = cache["k"].at[slot, :, row].set(k_new[:, 0])
-    v = cache["v"].at[slot, :, row].set(v_new[:, 0])
+    # attended unmasked).  One index per KV head, so each update is one
+    # (hd,) row: with (KV, hd) windows the TPU compiler re-lays the whole
+    # stack out around the scatter and back for the kernel
+    slot = jnp.take_along_axis(tables, (lens // page)[:, None], axis=1)
+    row = (lens % page)[:, None]
+    head = jnp.arange(k.shape[2], dtype=jnp.int32)[None, :]
+    k = k.at[li, slot, head, row].set(k_new[:, 0])
+    v = v.at[li, slot, head, row].set(v_new[:, 0])
     kv_len = lens + 1
     out = None
     if cfg.use_pallas:
-        out = _paged_attention_kernel(q, k, v, tables, kv_len,
+        out = _paged_attention_kernel(q, k, v, tables, kv_len, li,
                                       device=cfg.pallas_device)
     if out is None:
         # gather the tables into a dense (B, NB*page, KV, hd) cache and
         # run the plain decode path (which may still pick the contiguous
         # kernel when cfg.use_pallas is set)
-        out = attention(q, _gather_pages(k, tables),
-                        _gather_pages(v, tables), causal=False, kv_len=kv_len,
-                        use_pallas=cfg.use_pallas,
+        out = attention(q, _gather_pages(k, tables, li),
+                        _gather_pages(v, tables, li), causal=False,
+                        kv_len=kv_len, use_pallas=cfg.use_pallas,
                         pallas_device=cfg.pallas_device)
     y = dense(out.reshape(B, S, cfg.n_heads * cfg.hd), w["wo"])
-    return y, {"k": k, "v": v}
+    return y, _unstack(k, v, layer)
 
 
 def attn_prefill_paged(cfg: ModelConfig, w, x: jax.Array, cache: Dict,
                        block_tables: jax.Array, lens: jax.Array,
-                       n_valid: jax.Array, *,
+                       n_valid: jax.Array, layer=None, *,
                        aligned: bool = False) -> Tuple[jax.Array, Dict]:
     """One continuation-prefill chunk against the shared KV pool.
 
     x: (B, C, D) — a fixed-size chunk of each request's *uncached* prompt
-    suffix, right-padded past ``n_valid``; cache ``{"k", "v"}``: the
-    (P, KV, page, hd) block pools; block_tables (B, NB) / lens (B,) as in
+    suffix, right-padded past ``n_valid``; cache ``{"k", "v"}`` and
+    ``layer``, block_tables (B, NB) and lens (B,) as in
     :func:`attn_decode_paged` — ``lens`` is the number of tokens already
     in the cache, i.e. the chunk's global start position (both its write
     offset and its RoPE base).  The chunk's K/V rows are written into
@@ -471,15 +501,16 @@ def attn_prefill_paged(cfg: ModelConfig, w, x: jax.Array, cache: Dict,
     nv = jnp.asarray(n_valid, jnp.int32)
     positions = lens[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]
     q, k_new, v_new = _qkv(cfg, w, x, positions)
-    KV, page = cache["k"].shape[1], cache["k"].shape[2]
+    k, v, li = _layer_pools(cache, layer)
+    KV, page = k.shape[2], k.shape[3]
     tables = jnp.asarray(block_tables, jnp.int32)
     if aligned and B == 1 and C <= page:
         # single-block chunk: one contiguous C-row window per head
-        start = (tables[0, lens[0] // page], 0, lens[0] % page, 0)
+        start = (li, tables[0, lens[0] // page], 0, lens[0] % page, 0)
         k = jax.lax.dynamic_update_slice(
-            cache["k"], k_new.transpose(0, 2, 1, 3), start)
+            k, k_new.transpose(0, 2, 1, 3)[None], start)
         v = jax.lax.dynamic_update_slice(
-            cache["v"], v_new.transpose(0, 2, 1, 3), start)
+            v, v_new.transpose(0, 2, 1, 3)[None], start)
     else:
         # scatter the chunk's K/V rows at their global positions; rows
         # past n_valid (chunk padding) are redirected to the null block,
@@ -489,15 +520,16 @@ def attn_prefill_paged(cfg: ModelConfig, w, x: jax.Array, cache: Dict,
         blk = jnp.where(valid, jnp.take_along_axis(
             tables, positions // page, axis=1), 0)
         r = jnp.where(valid, positions % page, row % page)
-        k = cache["k"].at[blk, :, r].set(k_new)
-        v = cache["v"].at[blk, :, r].set(v_new)
+        head = jnp.arange(KV, dtype=jnp.int32)
+        k = k.at[li, blk[..., None], head, r[..., None]].set(k_new)
+        v = v.at[li, blk[..., None], head, r[..., None]].set(v_new)
     # read path: gather the table into a dense (B, NB*page, KV, hd) cache
     # (exactly the decode tick's read) and attend causally at each
     # request's own offset.  kv_len additionally masks rows the causal
     # mask cannot see when C == 1; for valid rows it masks a subset of
     # what causality already does, so the attended logits are unchanged.
-    kd = _gather_pages(k, tables)
-    vd = _gather_pages(v, tables)
+    kd = _gather_pages(k, tables, li)
+    vd = _gather_pages(v, tables, li)
     G = cfg.n_heads // KV
     if G > 1:
         kd = jnp.repeat(kd, G, axis=2)
@@ -505,7 +537,7 @@ def attn_prefill_paged(cfg: ModelConfig, w, x: jax.Array, cache: Dict,
     out = sdpa(q, kd, vd, causal=True, scale=1.0 / math.sqrt(cfg.hd),
                kv_len=lens + nv, q_offset=lens)
     y = dense(out.reshape(B, C, cfg.n_heads * cfg.hd), w["wo"])
-    return y, {"k": k, "v": v}
+    return y, _unstack(k, v, layer)
 
 
 # ---------------------------------------------------------------------------

@@ -241,11 +241,14 @@ def apply_layer(cfg: ModelConfig, sig: Sig, w, h: jax.Array, *,
 
 def apply_layer_paged(cfg: ModelConfig, sig: Sig, w, h: jax.Array,
                       cache: Dict, block_tables: jax.Array,
-                      lens: jax.Array):
+                      lens: jax.Array, layer=None):
     """One layer of a continuous-batching decode tick: like
     ``apply_layer(mode="decode")`` but against the shared block-paged KV
     pool, with per-request positions (``lens``) instead of a batch-wide
-    ``pos`` scalar.  Returns (h, new_cache); h is (B, 1, D).
+    ``pos`` scalar.  ``cache`` holds the (L, P, KV, page, hd) pool
+    stacks and ``layer`` this layer's index in them, or one layer's
+    pools with ``layer=None`` (see :func:`attn.attn_decode_paged`).
+    Returns (h, new_cache); h is (B, 1, D).
 
     Only plain GQA attention layers can page — the SSM state is O(1) and
     needs no paging, and MLA/cross caches have different leaf shapes —
@@ -260,7 +263,7 @@ def apply_layer_paged(cfg: ModelConfig, sig: Sig, w, h: jax.Array,
     hin = h
     x = norm_apply(cfg, w["ln1"], h)
     y, new_cache = attn.attn_decode_paged(cfg, w["mixer"], x, cache,
-                                          block_tables, lens)
+                                          block_tables, lens, layer)
     h = hin + y
     if "ffn" in w:
         z = norm_apply(cfg, w["ln2"], h)
@@ -272,12 +275,13 @@ def apply_layer_paged(cfg: ModelConfig, sig: Sig, w, h: jax.Array,
 def apply_layer_prefill_paged(cfg: ModelConfig, sig: Sig, w, h: jax.Array,
                               cache: Dict, block_tables: jax.Array,
                               lens: jax.Array, n_valid: jax.Array,
-                              aligned: bool = False):
+                              layer=None, aligned: bool = False):
     """One layer of a continuation-prefill chunk: like
     :func:`apply_layer_paged` but over a (B, C, D) chunk of prompt
     tokens instead of a single pending token — the chunk's K/V rows are
     written into the pool and attention reads the already-written
-    prefix back through the block table.  Returns (h, new_cache).
+    prefix back through the block table.  ``cache`` and ``layer`` as in
+    :func:`apply_layer_paged`.  Returns (h, new_cache).
     ``aligned`` passes through to :func:`attn.attn_prefill_paged`'s
     single-block fast write path.  Same paging restriction: plain GQA
     attention layers only.
@@ -291,7 +295,7 @@ def apply_layer_prefill_paged(cfg: ModelConfig, sig: Sig, w, h: jax.Array,
     x = norm_apply(cfg, w["ln1"], h)
     y, new_cache = attn.attn_prefill_paged(cfg, w["mixer"], x, cache,
                                            block_tables, lens, n_valid,
-                                           aligned=aligned)
+                                           layer, aligned=aligned)
     h = hin + y
     if "ffn" in w:
         z = norm_apply(cfg, w["ln2"], h)

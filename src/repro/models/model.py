@@ -413,11 +413,19 @@ def paged_decode_step(cfg: ModelConfig, params, cache: Dict,
     (B, NB) int32 logical->physical pool block maps (shared across
     layers: every layer's pool is indexed by the same table); lens (B,)
     int32 per-request cache lengths (write index AND RoPE position).
-    The cache pytree mirrors :func:`init_cache`'s structure but each
-    layer leaf is a (P, KV, page, hd) pool — build it with
-    ``repro.serve.PagedKVCache``.  Unlike :func:`decode_step` there is
-    no batch-wide ``pos``: slots decode at independent offsets, which is
-    what lets one compiled step serve ragged in-flight requests.
+    The cache pytree mirrors :func:`init_cache`'s structure: each
+    ``layers0`` leaf is one layer's (P, KV, page, hd) pool and each
+    ``layers`` leaf the (n_periods, P, KV, page, hd) stack of a period
+    slot's pools — build it with ``repro.serve.PagedKVCache``.  Unlike
+    :func:`decode_step` there is no batch-wide ``pos``: slots decode at
+    independent offsets, which is what lets one compiled step serve
+    ragged in-flight requests.
+
+    The layer scan carries the pool stacks and hands each layer its
+    index in them: a layer writes its new rows into the stack and reads
+    through the index, so no step slices a layer's pool out of the
+    stack or builds a second stack.  Donated by the caller, the stacks
+    are updated in place.
     """
     if cfg.pos_embed != "rope":
         raise NotImplementedError(
@@ -435,16 +443,19 @@ def paged_decode_step(cfg: ModelConfig, params, cache: Dict,
 
     slot_sigs = [sigs[first_k + s] for s in range(period)]
 
-    def body(h, x):
-        ws, cs = x
-        new_cs = []
+    def body(carry, x):
+        h, pools = carry
+        ws, i = x
+        new_pools = []
         for s in range(period):
-            h, nc = apply_layer_paged(cfg, slot_sigs[s], ws[s], h, cs[s],
-                                      block_tables, lens)
-            new_cs.append(nc)
-        return h, tuple(new_cs)
+            h, nc = apply_layer_paged(cfg, slot_sigs[s], ws[s], h, pools[s],
+                                      block_tables, lens, i)
+            new_pools.append(nc)
+        return (h, tuple(new_pools)), None
 
-    h, new_layers = jax.lax.scan(body, h, (params["layers"], cache["layers"]))
+    (h, new_layers), _ = jax.lax.scan(
+        body, (h, cache["layers"]),
+        (params["layers"], jnp.arange(n_periods, dtype=jnp.int32)))
     logits = _logits_out(cfg, params, h)
     return logits, {"layers0": new0, "layers": new_layers}
 
@@ -458,8 +469,9 @@ def paged_prefill_step(cfg: ModelConfig, params, cache: Dict,
     tokens (B, C) int32 — a fixed-size chunk of each request's uncached
     prompt suffix, right-padded past ``n_valid``; block_tables (B, NB)
     and lens (B,) as in :func:`paged_decode_step` (``lens`` = tokens
-    already cached = the chunk's global start position).  Each layer
-    scatters the chunk's K/V into the pool and attends back through the
+    already cached = the chunk's global start position), and so are the
+    cache pytree and the layer scan that carries its stacks.  Each layer
+    writes the chunk's K/V into the pool and attends back through the
     block table, so a chunk sees both earlier chunks of its own prompt
     AND any prefix blocks *shared* with other requests.  Returns the
     logits at each request's last valid chunk row (B, 1, V) — only
@@ -484,22 +496,26 @@ def paged_prefill_step(cfg: ModelConfig, params, cache: Dict,
     for i in range(first_k):
         h, nc = apply_layer_prefill_paged(cfg, sigs[i], params["layers0"][i],
                                           h, cache["layers0"][i],
-                                          block_tables, lens, nv, aligned)
+                                          block_tables, lens, nv,
+                                          aligned=aligned)
         new0.append(nc)
 
     slot_sigs = [sigs[first_k + s] for s in range(period)]
 
-    def body(h, x):
-        ws, cs = x
-        new_cs = []
+    def body(carry, x):
+        h, pools = carry
+        ws, i = x
+        new_pools = []
         for s in range(period):
             h, nc = apply_layer_prefill_paged(cfg, slot_sigs[s], ws[s], h,
-                                              cs[s], block_tables, lens, nv,
-                                              aligned)
-            new_cs.append(nc)
-        return h, tuple(new_cs)
+                                              pools[s], block_tables, lens,
+                                              nv, i, aligned=aligned)
+            new_pools.append(nc)
+        return (h, tuple(new_pools)), None
 
-    h, new_layers = jax.lax.scan(body, h, (params["layers"], cache["layers"]))
+    (h, new_layers), _ = jax.lax.scan(
+        body, (h, cache["layers"]),
+        (params["layers"], jnp.arange(n_periods, dtype=jnp.int32)))
     # logits only at the last valid row — sliced before the unembed, like
     # prefill's last_pos path, so the (B, C, V) tensor is never formed
     h_last = jnp.take_along_axis(h, (nv - 1)[:, None, None], axis=1)

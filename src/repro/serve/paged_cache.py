@@ -5,7 +5,9 @@ Cache layout
 Every attention layer owns two pools ``k``/``v`` of shape
 ``(P, KV, page, hd)``: ``P`` physical blocks of ``page`` token rows,
 head-major inside a block so each head's page is one contiguous
-``(page, hd)`` slab the TPU kernel can DMA as a tile.  A
+``(page, hd)`` slab the TPU kernel can DMA as a tile; the pools of
+the layers the model scans are stacked, ``(n_periods, P, KV, page,
+hd)``, and each step reads and writes them in place.  A
 request's cache is the *logical* concatenation of the blocks its row of
 the (B, NB) block table names — the table is shared across layers, so
 one allocation covers the whole model.  ``page`` is the MXU-aligned

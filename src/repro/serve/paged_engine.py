@@ -193,9 +193,11 @@ class PagedServeEngine:
             return logits, toks, new_c
 
         # the pool pytree is donated: run() threads one live pools value
-        # through every dispatch and never reads a superseded one, so XLA
-        # updates the blocks in place instead of copying the whole pool
-        # (MBs per tick) to preserve an input nobody looks at again
+        # through every dispatch and never reads a superseded one, so the
+        # step's output reuses the input's buffers.  The rows themselves
+        # are written in place by the layer scan in paged_decode_step,
+        # which carries the pool stacks and writes each layer's new rows
+        # at its index; donation only spares the copy of the input
         self._decode = jax.jit(_step, donate_argnums=(1,))
 
         # chunks start at multiples of prefill_chunk past a page boundary
@@ -213,7 +215,8 @@ class PagedServeEngine:
         # ONE compiled prefill: fixed (1, prefill_chunk) tokens against
         # the full table width, whatever the prompt length — the ragged
         # final chunk pads and masks via ``nv`` instead of recompiling.
-        # Pools donated for the same in-place reason as _decode.
+        # Pools donated as for _decode; paged_prefill_step writes the
+        # chunk's rows in place the same way.
         self._prefill = jax.jit(_pstep, donate_argnums=(1,))
 
     def _prompt_blocks(self, s: int) -> int:

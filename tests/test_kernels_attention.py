@@ -236,6 +236,35 @@ def test_paged_decode_ignores_unmapped_blocks():
                                atol=1e-6)
 
 
+@pytest.mark.parametrize("dt", [jnp.float32, jnp.bfloat16])
+def test_paged_decode_reads_a_layer_of_the_stack_in_place(dt):
+    """The stacked (L, P, KV, page, hd) call at layer i equals the 4-D
+    call on pool[i] bit for bit: ragged lengths, tables whose tails
+    point at the null block, and other layers' pages never read."""
+    L, B, NB, page, H, KV, hd = 3, 3, 3, 128, 4, 2, 32
+    rng = np.random.RandomState(5)
+    P = B * NB + 1
+    q = jnp.asarray(rng.randn(B, H, hd), dt)
+    k = jnp.asarray(rng.randn(L, P, KV, page, hd), dt)
+    v = jnp.asarray(rng.randn(L, P, KV, page, hd), dt)
+    lens = np.asarray([1, 200, 3 * page], np.int32)
+    blocks = iter(rng.permutation(np.arange(1, P)))
+    tables = np.zeros((B, NB), np.int32)         # tails: the null block
+    for b in range(B):
+        for j in range(-(-int(lens[b]) // page)):
+            tables[b, j] = next(blocks)
+    tables, kv_len = jnp.asarray(tables), jnp.asarray(lens)
+    for i in range(L):
+        y = ops.paged_decode_attention(q, k, v, tables, kv_len,
+                                       jnp.int32(i))
+        y4 = ops.paged_decode_attention(q, k[i], v[i], tables, kv_len)
+        np.testing.assert_array_equal(np.asarray(y), np.asarray(y4))
+        yr = ref.paged_decode_attention_ref(q, k[i], v[i], tables, kv_len)
+        np.testing.assert_allclose(np.asarray(y, np.float32),
+                                   np.asarray(yr, np.float32),
+                                   rtol=2e-2, atol=2e-2)
+
+
 def test_paged_decode_page_block_mismatch_raises():
     """A plan whose block_kv != the pool page is a geometry bug: raise."""
     import pytest

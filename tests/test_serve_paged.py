@@ -16,6 +16,7 @@ parity needs every attention contraction at the same aligned KV length
 (ragged exact-length prefill rounds its tail reduction differently).
 """
 
+import re
 import warnings
 
 import jax
@@ -200,6 +201,48 @@ def test_temperature_seed_control():
         np.testing.assert_array_equal(x.tokens, y.tokens)
     assert any(not np.array_equal(x.tokens, y.tokens)
                for x, y in zip(a, c))
+
+
+# ---------------------------------------------------------------------------
+# The layer scan carries the pool stacks: no step copies a layer's pool
+# ---------------------------------------------------------------------------
+
+_TENSOR = re.compile(r"tensor<([0-9x]+)x[a-z0-9]+>")
+
+
+def _pool_moves(text, pool_shape):
+    """The lowered module's dynamic_slice results and dynamic_update_slice
+    updates that are a whole layer's (1, P, KV, page, hd) pool."""
+    moves = []
+    for line in text.splitlines():
+        if "stablehlo.dynamic_slice" in line:
+            moved = _TENSOR.findall(line.split("->")[-1])[:1]
+        elif "stablehlo.dynamic_update_slice" in line:
+            moved = _TENSOR.findall(line.split(":")[-1])[1:2]
+        else:
+            continue
+        if moved and tuple(map(int, moved[0].split("x"))) == pool_shape:
+            moves.append(line.strip())
+    return moves
+
+
+@pytest.mark.parametrize("chunk", [32, 48])      # aligned write, row scatter
+def test_steps_never_slice_or_rebuild_a_layer_pool(chunk):
+    eng = _engine(max_len=256, max_batch=2, n_blocks=9, prefill_chunk=chunk)
+    k = eng.cache.pools["layers"][0]["k"]
+    assert k.shape[0] > 1                        # a stack the scan walks
+    pool_shape = (1,) + k.shape[1:]
+    B, NB = eng.max_batch, eng.nb_table
+    dec = eng._decode.lower(
+        PARAMS, eng.cache.pools, np.zeros((B, 1), np.int32),
+        np.zeros((B, NB), np.int32), np.zeros((B,), np.int32)).as_text()
+    pre = eng._prefill.lower(
+        PARAMS, eng.cache.pools, np.zeros((1, chunk), np.int32),
+        np.zeros((1, NB), np.int32), np.zeros((1,), np.int32),
+        np.full((1,), chunk, np.int32)).as_text()
+    assert "stablehlo.while" in dec and "stablehlo.while" in pre
+    assert _pool_moves(dec, pool_shape) == []
+    assert _pool_moves(pre, pool_shape) == []
 
 
 # ---------------------------------------------------------------------------

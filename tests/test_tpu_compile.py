@@ -71,6 +71,12 @@ def _cases():
                 q, k, v, t, n, **kw),
             [((B, H, hd), bf), (pool, bf), (pool, bf),
              ((B, T // page), i32), ((B,), i32)]),
+        # the serve engine's read: layer i of a stack of layer pools
+        "paged_decode_attention_stacked": (
+            lambda q, k, v, t, n, i: ops.paged_decode_attention(
+                q, k, v, t, n, i, **kw),
+            [((B, H, hd), bf), ((4,) + pool, bf), ((4,) + pool, bf),
+             ((B, T // page), i32), ((B,), i32), ((), i32)]),
         "mfma_gemm": (
             lambda a, b, c: ops.mfma_gemm(a, b, c, **kw),
             [((S, q7.d_model), bf), ((q7.d_model, q7.d_ff), bf),
@@ -88,8 +94,9 @@ def _cases():
 
 
 @pytest.mark.parametrize("kernel", ["flash_attention", "decode_attention",
-                                    "paged_decode_attention", "mfma_gemm",
-                                    "moe_gmm", "mamba2_ssd"])
+                                    "paged_decode_attention",
+                                    "paged_decode_attention_stacked",
+                                    "mfma_gemm", "moe_gmm", "mamba2_ssd"])
 def test_kernel_compiles_for_v5e(kernel, one_chip):
     fn, specs = _cases()[kernel]
     args = [jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
