@@ -24,7 +24,9 @@ Two variants share one kernel body:
   ``kv_len`` mask machinery handles the partial last block, and
   fully-masked blocks are skipped by ``pl.when`` exactly like the
   contiguous variant.  A single ``(P, KV, block_kv, hd)`` pool is the
-  stack with a unit layer axis.
+  stack with a unit layer axis.  Its latent mode serves multi-head
+  latent attention: a pool of latent rows that are keys and values at
+  once, and a pool of the decoupled rope keys, each page read once.
 """
 
 from __future__ import annotations
@@ -45,11 +47,15 @@ _NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
 def _decode_body(kv_len, ki, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
-                 acc_ref, *, scale: float, n_kv: int, block_kv: int):
+                 acc_ref, *, scale: float, n_kv: int, block_kv: int,
+                 kt_ref=None):
     """Shared online-softmax step: one (G, block_kv) score tile against the
     running (m, l, acc) scratch.  ``kv_len`` masks columns past the
     request's written prefix (the partial last block and, for the paged
-    variant, the whole tail of over-allocated table slots)."""
+    variant, the whole tail of over-allocated table slots).  Latent mode
+    (``v_ref=None``): the key tile (block_kv, R) is also the value tile,
+    and ``kt_ref``'s (rope, block_kv) tile adds the decoupled rope key,
+    scored against the query's columns past R."""
 
     @pl.when(ki == 0)
     def _init():
@@ -60,8 +66,15 @@ def _decode_body(kv_len, ki, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
     @pl.when(ki * block_kv < kv_len)
     def _compute():
         q = q_ref[0].astype(jnp.float32) * scale            # (G, hd)
-        k = k_ref[0].astype(jnp.float32)                    # (bkv, hd)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (G, bkv)
+        k = k_ref[0].astype(jnp.float32)                    # (bkv, R)
+        if kt_ref is None:
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))
+        else:
+            R = k.shape[1]
+            s = jax.lax.dot_general(q[:, :R], k, (((1,), (1,)), ((), ())))
+            s = s + jax.lax.dot_general(                    # (G, bkv)
+                q[:, R:], kt_ref[0].astype(jnp.float32),
+                (((1,), (0,)), ((), ())))
         col = ki * block_kv + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 1)
         s = jnp.where(col < kv_len, s, _NEG_INF)
@@ -70,8 +83,9 @@ def _decode_body(kv_len, ki, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
         l_ref[...] = l_ref[...] * corr + jnp.sum(p, -1, keepdims=True)
+        v = k_ref[0] if v_ref is None else v_ref[0]
         acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
@@ -150,27 +164,42 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return out.reshape(B, KV, G, hd).reshape(B, H, hd)
 
 
-def _paged_decode_kernel(tbl_ref, len_ref, layer_ref, q_ref, k_ref, v_ref,
-                         o_ref, m_ref, l_ref, acc_ref, *, scale: float,
-                         n_kv: int, block_kv: int, kv_heads: int):
+def _paged_decode_kernel(tbl_ref, len_ref, layer_ref, q_ref, k_ref, *refs,
+                         scale: float, n_kv: int, block_kv: int,
+                         kv_heads: int, latent: bool):
     # tbl_ref/len_ref/layer_ref are the scalar-prefetch operands; the K/V
-    # gather already happened in the BlockSpec index maps below.
+    # gather already happened in the BlockSpec index maps below.  Latent
+    # mode's second pool operand is the transposed rope key, not V.
     del tbl_ref, layer_ref
+    v_ref, kt_ref = (None, refs[0]) if latent else (refs[0], None)
+    o_ref, m_ref, l_ref, acc_ref = refs[1:]
     kv_len = len_ref[pl.program_id(0) // kv_heads]
     _decode_body(kv_len, pl.program_id(1), q_ref, k_ref, v_ref, o_ref,
                  m_ref, l_ref, acc_ref, scale=scale, n_kv=n_kv,
-                 block_kv=block_kv)
+                 block_kv=block_kv, kt_ref=kt_ref)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
-                           v_pool: jax.Array, block_tables: jax.Array,
+                           v_pool, block_tables: jax.Array,
                            kv_len: jax.Array, layer=None, *,
+                           k_rope_pool=None, scale: float = 0.0,
                            interpret: bool = False) -> jax.Array:
     """q: (B, H, hd); k_pool/v_pool: a (L, P, KV, block_kv, hd) stack of
     layer pools with ``layer`` the int32 index of the one to read, or a
     single (P, KV, block_kv, hd) pool with ``layer=None``;
     block_tables: (B, NB) int32 physical block ids; kv_len: (B,) int32.
+    ``scale`` is the softmax scale (0: 1/sqrt(hd)).
+
+    Latent mode (``v_pool=None``; multi-head latent attention in its
+    absorbed form): one head of latent rows, ``k_pool`` (..., 1,
+    block_kv, R), which are both the keys' first R columns and the
+    values, and ``k_rope_pool`` (..., 1, rope, block_kv), the decoupled
+    rope key stored transposed, a page of it one (rope, block_kv) tile;
+    q is (B, H, R + rope), the output (B, H, R).  Each page of each pool
+    is read once.  (A single (.., R + rope) row pool would be laid out
+    with its rows minor on the TPU, since R + rope is no multiple of 128,
+    and the kernel's read would copy the whole stack.)
 
     Each request attends its first ``kv_len[b]`` cache positions, read
     from pool blocks ``block_tables[b, 0..ceil(kv_len/block_kv))`` of
@@ -182,15 +211,20 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     null block — because the gather runs before the ``pl.when`` mask
     skips the compute.
     """
+    latent = v_pool is None
+    second = k_rope_pool if latent else v_pool
+    if latent and k_rope_pool is None:
+        raise ValueError("paged_decode_attention: latent mode (v_pool=None) "
+                         "needs k_rope_pool")
     if layer is None:
         # one pool is the stack with a unit layer axis (a bitcast)
-        k_pool, v_pool, layer = k_pool[None], v_pool[None], 0
+        k_pool, second, layer = k_pool[None], second[None], 0
     B, H, hd = q.shape
-    KV, block_kv = k_pool.shape[2], k_pool.shape[3]
+    KV, block_kv, dk = k_pool.shape[2], k_pool.shape[3], k_pool.shape[4]
     NB = block_tables.shape[1]
     G = H // KV
     T = NB * block_kv
-    scale = 1.0 / math.sqrt(hd)
+    scale = scale or 1.0 / math.sqrt(hd)
     validate_tiling("paged_decode_attention", {"T": (T, block_kv)},
                     depth_dims=(), block_names={"T": "block_kv"})
 
@@ -201,34 +235,33 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
 
     def _kv_index(i, j, tbl_ref, len_ref, layer_ref):
         # gather: grid step (i, j) reads kv head i % KV of physical block
-        # table[b, j] of the layer's pool — one contiguous (block_kv, hd)
-        # page
+        # table[b, j] of the layer's pool — one contiguous page
         del len_ref
         return (layer_ref[0], tbl_ref[i // KV, j], i % KV, 0, 0)
 
+    page = pl.BlockSpec((None, 1, None) + second.shape[3:], _kv_index)
+    dv = dk if latent else second.shape[-1]
     grid_spec = compat.prefetch_grid_spec(
         num_scalar_prefetch=3,
         grid=(B * KV, NB),
-        in_specs=[
-            pl.BlockSpec((1, G, hd), lambda i, j, t, n, y: (i, 0, 0)),
-            pl.BlockSpec((None, 1, None, block_kv, hd), _kv_index),
-            pl.BlockSpec((None, 1, None, block_kv, hd), _kv_index),
-        ],
-        out_specs=pl.BlockSpec((1, G, hd), lambda i, j, t, n, y: (i, 0, 0)),
+        in_specs=[pl.BlockSpec((1, G, hd), lambda i, j, t, n, y: (i, 0, 0)),
+                  pl.BlockSpec((None, 1, None, block_kv, dk), _kv_index),
+                  page],
+        out_specs=pl.BlockSpec((1, G, dv), lambda i, j, t, n, y: (i, 0, 0)),
         scratch_shapes=[
             compat.vmem((G, 1), jnp.float32),
             compat.vmem((G, 1), jnp.float32),
-            compat.vmem((G, hd), jnp.float32),
+            compat.vmem((G, dv), jnp.float32),
         ],
     )
 
     out = pl.pallas_call(
         functools.partial(_paged_decode_kernel, scale=scale, n_kv=NB,
-                          block_kv=block_kv, kv_heads=KV),
+                          block_kv=block_kv, kv_heads=KV, latent=latent),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B * KV, G, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B * KV, G, dv), q.dtype),
         compiler_params=compat.tpu_compiler_params(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(tables, lens, layers, qf, k_pool, v_pool)
-    return out.reshape(B, KV, G, hd).reshape(B, H, hd)
+    )(tables, lens, layers, qf, k_pool, second)
+    return out.reshape(B, KV, G, dv).reshape(B, H, dv)

@@ -220,7 +220,8 @@ def decode_attention(q, k, v, kv_len, *, device=None,
 
 
 def paged_decode_attention(q, k_pool, v_pool, block_tables, kv_len,
-                           layer=None, *, device=None,
+                           layer=None, *, k_rope_pool=None,
+                           scale: Optional[float] = None, device=None,
                            plan: Optional[TilePlan] = None,
                            block_kv: Optional[int] = None,
                            interpret: Optional[bool] = None):
@@ -229,7 +230,11 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, kv_len,
     q (B, H, hd); k_pool/v_pool a (L, P, KV, page, hd) stack of layer
     pools read at int32 index ``layer``, or one (P, KV, page, hd) pool
     with ``layer=None``; block_tables (B, NB) int32 physical block ids;
-    kv_len (B,) int32 per-request lengths.
+    kv_len (B,) int32 per-request lengths; ``scale`` the softmax scale
+    (default 1/sqrt(hd)).  Latent mode: ``v_pool=None``, ``k_pool`` the
+    latent rows (keys and values at once) and ``k_rope_pool`` the
+    transposed rope keys (see
+    :func:`repro.kernels.decode_attention.paged_decode_attention`).
     The pool's page size IS the kv tile, so the plan's ``block_kv`` must
     equal it — the ``shapes["page"]`` pin makes the planner agree on
     every device; there is no ``pad=`` mode (pool geometry is aligned by
@@ -250,6 +255,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, kv_len,
             "page so the gather granularity matches")
     return _da.paged_decode_attention(
         q, k_pool, v_pool, block_tables, kv_len, layer,
+        k_rope_pool=k_rope_pool, scale=float(scale or 0.0),
         interpret=compat.resolve_interpret(interpret))
 
 

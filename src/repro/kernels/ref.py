@@ -24,13 +24,13 @@ def mfma_gemm_ref(a, b, c):
     return d.astype(c.dtype)
 
 
-def _grouped_full_attn(q, k, v, *, causal, kv_len=None):
+def _grouped_full_attn(q, k, v, *, causal, kv_len=None, scale=None):
     B, S, H, hd = q.shape
     T, KV = k.shape[1], k.shape[2]
     G = H // KV
     qg = q.reshape(B, S, KV, G, hd).astype(jnp.float32)
     s = jnp.einsum("bskgh,btkh->bkgst", qg, k.astype(jnp.float32))
-    s = s / math.sqrt(hd)
+    s = s * (scale or 1.0 / math.sqrt(hd))
     if causal:
         i = jnp.arange(S)[:, None]
         j = jnp.arange(T)[None, :]
@@ -51,25 +51,35 @@ def flash_attention_ref(q, k, v, *, causal=True):
     return _grouped_full_attn(q, k, v, causal=causal)
 
 
-def decode_attention_ref(q, k, v, kv_len):
+def decode_attention_ref(q, k, v, kv_len, scale=None):
     """q (B, H, hd) single-token attention vs cache prefix < kv_len
     (an int32 scalar, or a per-request (B,) vector)."""
-    o = _grouped_full_attn(q[:, None], k, v, causal=False, kv_len=kv_len)
+    o = _grouped_full_attn(q[:, None], k, v, causal=False, kv_len=kv_len,
+                           scale=scale)
     return o[:, 0]
 
 
-def paged_decode_attention_ref(q, k_pool, v_pool, block_tables, kv_len):
+def paged_decode_attention_ref(q, k_pool, v_pool, block_tables, kv_len, *,
+                               k_rope_pool=None, scale=None):
     """Oracle for the paged kernel: gather each request's blocks from the
     (P, KV, bs, hd) pool into a dense (B, NB*bs, KV, hd) cache, then run
-    the plain decode oracle with per-request lengths."""
+    the plain decode oracle with per-request lengths.  Latent mode
+    (``v_pool=None``): the keys are the gathered latent rows followed by
+    the gathered (transposed back) rope keys, the values the latent rows
+    alone."""
     B = q.shape[0]
-    KV, hd = k_pool.shape[1], k_pool.shape[3]
 
-    def dense(pool):                 # (B, NB, KV, bs, hd) -> (B, T, KV, hd)
+    def dense(pool):                 # (B, NB, KV, bs, d) -> (B, T, KV, d)
         return pool[block_tables].transpose(0, 1, 3, 2, 4).reshape(
-            B, -1, KV, hd)
+            B, -1, pool.shape[1], pool.shape[3])
 
-    return decode_attention_ref(q, dense(k_pool), dense(v_pool), kv_len)
+    if v_pool is None:
+        v = dense(k_pool)
+        k = jnp.concatenate([v, dense(jnp.swapaxes(k_rope_pool, -1, -2))],
+                            axis=-1)
+    else:
+        k, v = dense(k_pool), dense(v_pool)
+    return decode_attention_ref(q, k, v, kv_len, scale=scale)
 
 
 def mamba2_ssd_ref(x, dt, A, Bm, Cm):

@@ -46,11 +46,13 @@ import jax.numpy as jnp
 from repro.kernels import dispatch as kdispatch
 from repro.kernels import ops as kops
 from repro.models.config import ModelConfig
-from repro.models.layers import cdtype, dense, mm, norm_apply, rope
+from repro.models.layers import (cdtype, dense, mm, norm_apply, rope,
+                                 yarn_mscale)
 from repro.parallel.api import current_mesh, shard
 
 __all__ = ["init_attn", "attn_train", "attn_decode", "attn_decode_paged",
            "attn_prefill_paged", "init_mla", "mla_train", "mla_decode",
+           "mla_decode_paged", "mla_prefill_paged", "mla_scale",
            "init_cross", "cross_train", "cross_decode", "init_attn_cache",
            "init_mla_cache", "sdpa", "attention"]
 
@@ -564,13 +566,35 @@ def init_mla(cfg: ModelConfig, key) -> Dict:
     }
 
 
+def mla_scale(cfg: ModelConfig) -> float:
+    """MLA's softmax scale: 1/sqrt(qk_nope + qk_rope), times YaRN's
+    ``mscale(factor, mscale_all_dim)**2`` when the config scales RoPE."""
+    m, y = cfg.mla, cfg.rope_scaling
+    s = 1.0 / math.sqrt(m.qk_nope_dim + m.qk_rope_dim)
+    if y is not None:
+        s *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return s
+
+
 def _mla_latent(cfg: ModelConfig, w, x, positions):
     m = cfg.mla
     dkv = dense(x, w["w_dkv"])
     c_kv, k_pe = dkv[..., :m.kv_lora_rank], dkv[..., m.kv_lora_rank:]
     c_kv = norm_apply(cfg, w["kv_norm"], c_kv)
-    k_pe = rope(k_pe[:, :, None, :], positions, cfg.rope_theta)[:, :, 0, :]
+    k_pe = rope(k_pe[:, :, None, :], positions, cfg.rope_theta,
+                cfg.rope_scaling)[:, :, 0, :]
     return c_kv, k_pe
+
+
+def _mla_query(cfg: ModelConfig, w, x, positions):
+    """(q_nope (B, S, H, nope), roped q_rope (B, S, H, rope))."""
+    m = cfg.mla
+    B, S = x.shape[:2]
+    q = dense(x, w["wq"]).reshape(B, S, cfg.n_heads,
+                                  m.qk_nope_dim + m.qk_rope_dim)
+    q_rope = rope(q[..., m.qk_nope_dim:], positions, cfg.rope_theta,
+                  cfg.rope_scaling)
+    return q[..., :m.qk_nope_dim], q_rope
 
 
 def _mla_attend(cfg: ModelConfig, w, x, c_kv, k_rope, positions, *,
@@ -578,10 +602,7 @@ def _mla_attend(cfg: ModelConfig, w, x, c_kv, k_rope, positions, *,
     m = cfg.mla
     B, S = x.shape[:2]
     T, H = c_kv.shape[1], cfg.n_heads
-    qk = m.qk_nope_dim + m.qk_rope_dim
-    q = dense(x, w["wq"]).reshape(B, S, H, qk)
-    q_nope, q_rope = q[..., :m.qk_nope_dim], q[..., m.qk_nope_dim:]
-    q_rope = rope(q_rope, positions, cfg.rope_theta)
+    q_nope, q_rope = _mla_query(cfg, w, x, positions)
     k_nope = dense(c_kv, w["w_uk"]).reshape(B, T, H, m.qk_nope_dim)
     v = dense(c_kv, w["w_uv"]).reshape(B, T, H, m.v_head_dim)
     k_rope_h = jnp.broadcast_to(k_rope[:, :, None, :], (B, T, H, m.qk_rope_dim))
@@ -589,7 +610,7 @@ def _mla_attend(cfg: ModelConfig, w, x, c_kv, k_rope, positions, *,
     k_full = _shard_kv(jnp.concatenate([k_nope, k_rope_h], axis=-1))
     v = _shard_kv(v)
     out = attention(q_full, k_full, v, causal=causal,
-                    scale=1.0 / math.sqrt(qk), kv_len=kv_len,
+                    scale=mla_scale(cfg), kv_len=kv_len,
                     use_pallas=cfg.use_pallas,
                     pallas_device=cfg.pallas_device)
     return dense(out.reshape(B, S, H * m.v_head_dim), w["wo"])
@@ -621,6 +642,158 @@ def mla_decode(cfg: ModelConfig, w, x: jax.Array, cache: Dict,
     y = _mla_attend(cfg, w, x, ckv, krope, positions, causal=False,
                     kv_len=pos + 1)
     return y, {"ckv": ckv, "krope": krope}
+
+
+def _latent_pools(cache: Dict, layer):
+    """(ckv, kpe, index): the (L, P, 1, page, R) latent and (L, P, 1,
+    rope, page) transposed rope-key stacks as they are, or one layer's
+    pools (``layer=None``) as stacks with a unit layer axis."""
+    if layer is None:
+        return cache["ckv"][None], cache["kpe"][None], jnp.int32(0)
+    return cache["ckv"], cache["kpe"], jnp.asarray(layer, jnp.int32)
+
+
+def _latent_cache(ckv, kpe, layer) -> Dict:
+    return ({"ckv": ckv, "kpe": kpe} if layer is not None
+            else {"ckv": ckv[0], "kpe": kpe[0]})
+
+
+def _gather_latent(ckv, kpe, tables, li):
+    """Layer ``li``'s dense (B, T, 1, R + rope) latent keys."""
+    B = tables.shape[0]
+    c = ckv[li, tables, 0].reshape(B, -1, 1, ckv.shape[-1])
+    r = jnp.swapaxes(kpe[li, tables, 0], -1, -2).reshape(B, -1, 1,
+                                                         kpe.shape[-2])
+    return jnp.concatenate([c, r], axis=-1)
+
+
+def mla_decode_paged(cfg: ModelConfig, w, x: jax.Array, cache: Dict,
+                     block_tables: jax.Array, lens: jax.Array,
+                     layer=None) -> Tuple[jax.Array, Dict]:
+    """One continuous-batching MLA decode step against the latent pool,
+    in the absorbed form.
+
+    x (B, 1, D); cache ``{"ckv", "kpe"}`` and ``layer``, block_tables and
+    lens as in :func:`attn_decode_paged`.  Each token writes one latent
+    row (c_kv | k_rope) per layer.  The query folds ``w_uk`` in, so it
+    attends over the latent rows as ONE key head of width R + rope whose
+    values are the rows' first R columns — the paged kernel's latent
+    mode; ``w_uv`` is applied to the R-wide result.  No cached row is
+    ever up-projected."""
+    m = cfg.mla
+    B, S, D = x.shape
+    H, R = cfg.n_heads, m.kv_lora_rank
+    lens = jnp.asarray(lens, jnp.int32)
+    c_new, k_new = _mla_latent(cfg, w, x, lens[:, None])
+    ckv, kpe, li = _latent_pools(cache, layer)
+    page = ckv.shape[3]
+    tables = jnp.asarray(block_tables, jnp.int32)
+    slot = jnp.take_along_axis(tables, (lens // page)[:, None], axis=1)[:, 0]
+    ckv = ckv.at[li, slot, 0, lens % page].set(c_new[:, 0])
+    kpe = kpe.at[li, slot, 0, :, lens % page].set(k_new[:, 0])
+    q_nope, q_rope = _mla_query(cfg, w, x, lens[:, None])
+    q_lat = mm("bshn,rhn->bshr", q_nope,
+               w["w_uk"].reshape(R, H, m.qk_nope_dim), out_dtype=x.dtype)
+    q = jnp.concatenate([q_lat, q_rope], axis=-1)       # (B, 1, H, R + rope)
+    kv_len = lens + 1
+    out = None
+    if cfg.use_pallas:
+        NB = tables.shape[1]
+        dec = kdispatch.decide(
+            "paged_decode_attention",
+            {"B": B, "T": NB * page, "H": H, "KV": 1, "hd": q.shape[-1],
+             "page": page},
+            dtype=q.dtype, device=cfg.pallas_device,
+            sharded=current_mesh() is not None)
+        if dec.use_kernel:
+            out = kops.paged_decode_attention(
+                q[:, 0], ckv, None, tables, kv_len, li, k_rope_pool=kpe,
+                scale=mla_scale(cfg), plan=dec.plan)[:, None]
+    if out is None:
+        lat = _gather_latent(ckv, kpe, tables, li)      # (B, T, 1, R + rope)
+        out = attention(q, lat, lat[..., :R], causal=False,
+                        scale=mla_scale(cfg), kv_len=kv_len)
+    o = mm("bshr,rhv->bshv", out, w["w_uv"].reshape(R, H, m.v_head_dim),
+           out_dtype=x.dtype)
+    y = dense(o.reshape(B, S, H * m.v_head_dim), w["wo"])
+    return y, _latent_cache(ckv, kpe, layer)
+
+
+def mla_prefill_paged(cfg: ModelConfig, w, x: jax.Array, cache: Dict,
+                      block_tables: jax.Array, lens: jax.Array,
+                      n_valid: jax.Array, layer=None, *,
+                      aligned: bool = False) -> Tuple[jax.Array, Dict]:
+    """One continuation-prefill chunk of MLA against the latent pool.
+
+    Arguments as in :func:`attn_prefill_paged`.  The chunk writes its
+    latent rows, then attends in the up-projected form: a loop over the
+    table's pages up to the chunk's last valid row (later pages hold
+    nothing it may see) up-projects each page's latent rows to per-head
+    keys and values and folds them into an online softmax, so no
+    table-wide K/V or score tensor is formed."""
+    m = cfg.mla
+    B, C, D = x.shape
+    H, R = cfg.n_heads, m.kv_lora_rank
+    lens = jnp.asarray(lens, jnp.int32)
+    nv = jnp.asarray(n_valid, jnp.int32)
+    positions = lens[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]
+    c_new, k_new = _mla_latent(cfg, w, x, positions)    # (B, C, R), rope
+    ckv, kpe, li = _latent_pools(cache, layer)
+    page = ckv.shape[3]
+    tables = jnp.asarray(block_tables, jnp.int32)
+    if aligned and B == 1 and C <= page:
+        blk, off = tables[0, lens[0] // page], lens[0] % page
+        ckv = jax.lax.dynamic_update_slice(ckv, c_new[:, None][None],
+                                           (li, blk, 0, off, 0))
+        kpe = jax.lax.dynamic_update_slice(
+            kpe, jnp.swapaxes(k_new, 1, 2)[:, None][None],
+            (li, blk, 0, 0, off))
+    else:
+        # rows past n_valid go to the null block, never attended unmasked
+        row = jnp.arange(C, dtype=jnp.int32)[None, :]
+        valid = row < nv[:, None]
+        blk = jnp.where(valid, jnp.take_along_axis(
+            tables, positions // page, axis=1), 0)
+        r = jnp.where(valid, positions % page, row % page)
+        ckv = ckv.at[li, blk, 0, r].set(c_new)
+        kpe = kpe.at[li, blk, 0, :, r].set(k_new)
+
+    q_nope, q_rope = _mla_query(cfg, w, x, positions)
+    q = jnp.concatenate([q_nope, q_rope], axis=-1)      # (B, C, H, qk)
+    scale = mla_scale(cfg)
+    end = lens + nv                                     # rows in the cache
+    w_uk, w_uv = w["w_uk"], w["w_uv"]
+
+    def page_step(j, carry):
+        mx, l, acc = carry
+        c = ckv[li, tables[:, j], 0]                    # (B, page, R)
+        k_pe = jnp.swapaxes(kpe[li, tables[:, j], 0], 1, 2)   # (B, page, rope)
+        k_nope = dense(c, w_uk).reshape(B, page, H, m.qk_nope_dim)
+        v = dense(c, w_uv).reshape(B, page, H, m.v_head_dim)
+        k = jnp.concatenate([k_nope, jnp.broadcast_to(
+            k_pe[:, :, None], (B, page, H, m.qk_rope_dim))], axis=-1)
+        s = mm("bshd,bthd->bhst", q, k) * scale         # (B, H, C, page)
+        col = j * page + jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, page),
+                                                  3)
+        see = (col <= positions[:, None, :, None]) & (
+            col < end[:, None, None, None])
+        s = jnp.where(see, s, _NEG_INF)
+        m_new = jnp.maximum(mx, jnp.max(s, axis=-1))
+        p = jnp.exp(s - m_new[..., None])
+        corr = jnp.exp(mx - m_new)
+        return (m_new, l * corr + jnp.sum(p, axis=-1),
+                acc * corr[..., None]
+                + mm("bhst,bthd->bhsd", p.astype(v.dtype), v))
+
+    n_pages = (jnp.max(end) + page - 1) // page
+    init = (jnp.full((B, H, C), _NEG_INF, jnp.float32),
+            jnp.zeros((B, H, C), jnp.float32),
+            jnp.zeros((B, H, C, m.v_head_dim), jnp.float32))
+    _, l, acc = jax.lax.fori_loop(0, n_pages, page_step, init)
+    out = (acc / jnp.maximum(l, 1e-30)[..., None]).astype(x.dtype)
+    y = dense(out.transpose(0, 2, 1, 3).reshape(B, C, H * m.v_head_dim),
+              w["wo"])
+    return y, _latent_cache(ckv, kpe, layer)
 
 
 # ---------------------------------------------------------------------------

@@ -35,7 +35,7 @@ from repro.models import attention as attn
 from repro.models import ssm as ssm_mod
 from repro.models.config import ModelConfig
 from repro.models.layers import cdtype, mlp_apply, norm_apply
-from repro.models.moe import init_moe, moe_apply
+from repro.models.moe import init_moe, moe_apply, moe_serve
 
 __all__ = ["Sig", "layer_sigs", "schedule", "init_layer", "init_layer_cache",
            "apply_layer", "apply_layer_paged", "apply_layer_prefill_paged",
@@ -239,37 +239,55 @@ def apply_layer(cfg: ModelConfig, sig: Sig, w, h: jax.Array, *,
     return h, new_cache
 
 
+def _paged_mixer_check(sig: Sig, fn: str) -> None:
+    if sig[0] != "attn":
+        raise NotImplementedError(
+            f"{fn}: only attention layers (GQA, or MLA's latent rows) "
+            f"page; SSM state and cross-attention caches do not (got "
+            f"mixer={sig[0]!r})")
+
+
+def _ffn_serve(cfg: ModelConfig, sig: Sig, w, h, valid):
+    """The block's FFN half when serving: (h, routed).  A MoE layer runs
+    dropless (``moe_serve``) over the rows ``valid`` marks and returns
+    the assignments its held experts computed; ``routed`` is None for a
+    dense FFN."""
+    if "ffn" not in w:
+        return h, None
+    z = norm_apply(cfg, w["ln2"], h)
+    if sig[1]:
+        f, routed = moe_serve(cfg, w["ffn"], z, valid)
+        return h + f, routed
+    return h + mlp_apply(cfg, w["ffn"], z), None
+
+
 def apply_layer_paged(cfg: ModelConfig, sig: Sig, w, h: jax.Array,
                       cache: Dict, block_tables: jax.Array,
                       lens: jax.Array, layer=None):
     """One layer of a continuous-batching decode tick: like
-    ``apply_layer(mode="decode")`` but against the shared block-paged KV
+    ``apply_layer(mode="decode")`` but against the shared block-paged
     pool, with per-request positions (``lens``) instead of a batch-wide
     ``pos`` scalar.  ``cache`` holds the (L, P, KV, page, hd) pool
-    stacks and ``layer`` this layer's index in them, or one layer's
-    pools with ``layer=None`` (see :func:`attn.attn_decode_paged`).
-    Returns (h, new_cache); h is (B, 1, D).
+    stacks — for MLA the latent ``ckv`` and rope-key ``kpe`` stacks — and
+    ``layer`` this layer's index in them, or one layer's pools with
+    ``layer=None`` (see :func:`attn.attn_decode_paged`).
+    Returns (h, new_cache, routed); h is (B, 1, D), ``routed`` the MoE
+    assignments computed for the active slots (lens > 0), or None.
 
-    Only plain GQA attention layers can page — the SSM state is O(1) and
-    needs no paging, and MLA/cross caches have different leaf shapes —
-    so heterogeneous schedules raise rather than silently mixing cache
-    layouts (``PagedKVCache`` rejects such configs up front).
+    Only attention layers page — the SSM state is O(1) and needs no
+    paging, cross-attention caches have other leaves — so heterogeneous
+    schedules raise rather than silently mixing cache layouts
+    (``PagedKVCache`` rejects such configs up front).
     """
-    mixer, _ = sig
-    if mixer != "attn" or cfg.mla:
-        raise NotImplementedError(
-            f"apply_layer_paged: only plain GQA attention layers page "
-            f"(got mixer={mixer!r}, mla={bool(cfg.mla)})")
+    _paged_mixer_check(sig, "apply_layer_paged")
     hin = h
     x = norm_apply(cfg, w["ln1"], h)
-    y, new_cache = attn.attn_decode_paged(cfg, w["mixer"], x, cache,
-                                          block_tables, lens, layer)
-    h = hin + y
-    if "ffn" in w:
-        z = norm_apply(cfg, w["ln2"], h)
-        f, _ = _ffn(cfg, sig, w, z)
-        h = h + f
-    return h, new_cache
+    mixer = attn.mla_decode_paged if cfg.mla else attn.attn_decode_paged
+    y, new_cache = mixer(cfg, w["mixer"], x, cache, block_tables, lens,
+                         layer)
+    valid = (jnp.asarray(lens) > 0)[:, None]
+    h, routed = _ffn_serve(cfg, sig, w, hin + y, valid)
+    return h, new_cache, routed
 
 
 def apply_layer_prefill_paged(cfg: ModelConfig, sig: Sig, w, h: jax.Array,
@@ -278,30 +296,24 @@ def apply_layer_prefill_paged(cfg: ModelConfig, sig: Sig, w, h: jax.Array,
                               layer=None, aligned: bool = False):
     """One layer of a continuation-prefill chunk: like
     :func:`apply_layer_paged` but over a (B, C, D) chunk of prompt
-    tokens instead of a single pending token — the chunk's K/V rows are
-    written into the pool and attention reads the already-written
-    prefix back through the block table.  ``cache`` and ``layer`` as in
-    :func:`apply_layer_paged`.  Returns (h, new_cache).
-    ``aligned`` passes through to :func:`attn.attn_prefill_paged`'s
-    single-block fast write path.  Same paging restriction: plain GQA
-    attention layers only.
+    tokens instead of a single pending token — the chunk's K/V (or
+    latent) rows are written into the pool and attention reads the
+    already-written prefix back through the block table.  ``cache`` and
+    ``layer`` as in :func:`apply_layer_paged`.  Returns (h, new_cache,
+    routed), ``routed`` counting the chunk's valid rows only.
+    ``aligned`` passes through to the attention's single-block fast
+    write path.  Same paging restriction: attention layers only.
     """
-    mixer, _ = sig
-    if mixer != "attn" or cfg.mla:
-        raise NotImplementedError(
-            f"apply_layer_prefill_paged: only plain GQA attention layers "
-            f"page (got mixer={mixer!r}, mla={bool(cfg.mla)})")
+    _paged_mixer_check(sig, "apply_layer_prefill_paged")
     hin = h
     x = norm_apply(cfg, w["ln1"], h)
-    y, new_cache = attn.attn_prefill_paged(cfg, w["mixer"], x, cache,
-                                           block_tables, lens, n_valid,
-                                           layer, aligned=aligned)
-    h = hin + y
-    if "ffn" in w:
-        z = norm_apply(cfg, w["ln2"], h)
-        f, _ = _ffn(cfg, sig, w, z)
-        h = h + f
-    return h, new_cache
+    mixer = attn.mla_prefill_paged if cfg.mla else attn.attn_prefill_paged
+    y, new_cache = mixer(cfg, w["mixer"], x, cache, block_tables, lens,
+                         n_valid, layer, aligned=aligned)
+    C = h.shape[1]
+    valid = jnp.arange(C)[None, :] < jnp.asarray(n_valid)[:, None]
+    h, routed = _ffn_serve(cfg, sig, w, hin + y, valid)
+    return h, new_cache, routed
 
 
 def _attn_prefill_cache(cfg: ModelConfig, w, x, positions, max_len):

@@ -11,13 +11,13 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional
 
-__all__ = ["ModelConfig", "MoESpec", "MLASpec", "SSMSpec", "CrossAttnSpec",
-           "EncoderSpec"]
+__all__ = ["ModelConfig", "MoESpec", "MLASpec", "YarnSpec", "SSMSpec",
+           "CrossAttnSpec", "EncoderSpec"]
 
 
 @dataclasses.dataclass(frozen=True)
 class MoESpec:
-    n_experts: int
+    n_experts: int               # the router's width: every expert of the layer
     top_k: int
     d_ff_expert: int
     n_shared: int = 0            # always-on shared experts (deepseek)
@@ -25,6 +25,16 @@ class MoESpec:
     period: int = 1              # MoE every `period` layers (jamba: 2)
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.001
+    norm_topk: bool = True       # renormalise the top-k gates to sum to 1
+    # expert-parallel share: this device holds experts [held_offset,
+    # held_offset + n_held) of the n_experts the router scores, computes
+    # their part of the layer, and leaves out the others' (0 = all held)
+    n_held: int = 0
+    held_offset: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -35,6 +45,30 @@ class MLASpec:
     qk_nope_dim: int = 128
     qk_rope_dim: int = 64
     v_head_dim: int = 128
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnSpec:
+    """YaRN RoPE scaling as DeepSeek-V2 publishes it (``rope_scaling``
+    with ``type: yarn``): inverse frequencies ramp from the original ones
+    to ones ``factor`` times slower between the correction dims of
+    ``beta_fast`` and ``beta_slow`` rotations at
+    ``original_max_position``; the softmax scale gains ``mscale(factor,
+    mscale_all_dim)**2``.  Cos and sin would be scaled by
+    ``mscale(factor, mscale) / mscale(factor, mscale_all_dim)``, which
+    is 1 with the two equal, as every published YaRN config has them."""
+    factor: float = 40.0
+    original_max_position: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 1.0
+
+    def __post_init__(self):
+        if self.mscale != self.mscale_all_dim:
+            raise ValueError(
+                f"YarnSpec: mscale {self.mscale} != mscale_all_dim "
+                f"{self.mscale_all_dim} (a cos/sin scale) is not modelled")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -80,6 +114,7 @@ class ModelConfig:
     norm_type: str = "rms"       # rms | layer
     mlp_type: str = "swiglu"     # swiglu | gelu
     pos_embed: str = "rope"      # rope | learned | none
+    rope_scaling: Optional[YarnSpec] = None
     tie_embeddings: bool = False
     moe: Optional[MoESpec] = None
     mla: Optional[MLASpec] = None
@@ -112,6 +147,15 @@ class ModelConfig:
     # buffer AND the cross-device gradient reduction wire bytes at ~3 bits
     # of accumulated-mantissa cost (used by the largest MoE config)
     grad_accum_dtype: str = "float32"
+
+    def __post_init__(self):
+        # nested specs may come as plain dicts (a configuration file's
+        # groups): build the dataclasses from them
+        for name, cls in (("moe", MoESpec), ("mla", MLASpec),
+                          ("rope_scaling", YarnSpec)):
+            v = getattr(self, name)
+            if isinstance(v, dict):
+                object.__setattr__(self, name, cls(**v))
 
     @property
     def hd(self) -> int:
@@ -150,7 +194,8 @@ class ModelConfig:
         if self.moe:
             kw["moe"] = dataclasses.replace(
                 self.moe, n_experts=4, top_k=2, d_ff_expert=64,
-                d_ff_shared=64 if self.moe.n_shared else 0)
+                d_ff_shared=64 if self.moe.n_shared else 0, n_held=0,
+                held_offset=0)
         if self.mla:
             kw["mla"] = MLASpec(kv_lora_rank=32, q_lora_rank=0,
                                 qk_nope_dim=16, qk_rope_dim=16, v_head_dim=16)

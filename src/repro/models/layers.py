@@ -7,6 +7,7 @@ matmul accumulation in f32 via ``preferred_element_type``.
 
 from __future__ import annotations
 
+import math
 import os
 from typing import Optional
 
@@ -16,7 +17,8 @@ import jax.numpy as jnp
 from repro.models.config import ModelConfig
 from repro.parallel.api import shard
 
-__all__ = ["dense", "mm", "norm_apply", "rope", "mlp_apply", "embed_apply",
+__all__ = ["dense", "mm", "norm_apply", "rope", "rope_inv_freq",
+           "yarn_mscale", "mlp_apply", "embed_apply",
            "unembed_apply", "DTYPES", "cdtype"]
 
 DTYPES = {"bfloat16": jnp.bfloat16, "float32": jnp.float32,
@@ -76,13 +78,44 @@ def norm_apply(cfg: ModelConfig, w, x: jax.Array) -> jax.Array:
     return y.astype(x.dtype)
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary embedding. x: (..., seq, heads, head_dim); positions: (seq,)
-    or (batch, seq)."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """DeepSeek-V2's ``yarn_get_mscale``: 0.1 * mscale * ln(factor) + 1."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rope_inv_freq(hd: int, theta: float, scaling=None) -> jax.Array:
+    """(hd // 2,) inverse frequencies, ``theta ** (-2i / hd)``; with a
+    :class:`~repro.models.config.YarnSpec` the DeepSeek-V2 YaRN mix: a
+    linear ramp over the pair index between the correction dims of
+    ``beta_fast`` and ``beta_slow`` rotations at the original length
+    keeps the fast pairs and slows the others by ``factor``."""
+    half = hd // 2
+    extra = jnp.exp(-jnp.arange(0, half, dtype=jnp.float32)
+                    * (jnp.log(theta) / half))
+    if scaling is None:
+        return extra
+
+    def corr_dim(rot):
+        return hd * math.log(scaling.original_max_position
+                             / (rot * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(corr_dim(scaling.beta_fast)), 0)
+    high = min(math.ceil(corr_dim(scaling.beta_slow)), hd - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return extra / scaling.factor * ramp + extra * (1.0 - ramp)
+
+
+def rope(x: jax.Array, positions: jax.Array, theta: float,
+         scaling=None) -> jax.Array:
+    """Rotary embedding (rotate-half pairs). x: (..., seq, heads,
+    head_dim); positions: (seq,) or (batch, seq); ``scaling`` a YaRN
+    spec or None."""
     hd = x.shape[-1]
     half = hd // 2
-    freqs = jnp.exp(-jnp.arange(0, half, dtype=jnp.float32)
-                    * (jnp.log(theta) / half))
+    freqs = rope_inv_freq(hd, theta, scaling)
     ang = positions.astype(jnp.float32)[..., None] * freqs       # (.., S, half)
     # broadcast over the heads axis: (..., S, 1, half)
     ang = ang[..., None, :]

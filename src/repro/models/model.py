@@ -404,120 +404,119 @@ def prefill(cfg: ModelConfig, params, batch: Dict, max_len: int,
     return logits[:, -1:], cache
 
 
-def paged_decode_step(cfg: ModelConfig, params, cache: Dict,
-                      tokens: jax.Array, block_tables: jax.Array,
-                      lens: jax.Array) -> Tuple[jax.Array, Dict]:
-    """One continuous-batching decode tick over the block-paged cache.
-
-    tokens (B, 1) int32 — each engine slot's pending token; block_tables
-    (B, NB) int32 logical->physical pool block maps (shared across
-    layers: every layer's pool is indexed by the same table); lens (B,)
-    int32 per-request cache lengths (write index AND RoPE position).
-    The cache pytree mirrors :func:`init_cache`'s structure: each
-    ``layers0`` leaf is one layer's (P, KV, page, hd) pool and each
-    ``layers`` leaf the (n_periods, P, KV, page, hd) stack of a period
-    slot's pools — build it with ``repro.serve.PagedKVCache``.  Unlike
-    :func:`decode_step` there is no batch-wide ``pos``: slots decode at
-    independent offsets, which is what lets one compiled step serve
-    ragged in-flight requests.
-
-    The layer scan carries the pool stacks and hands each layer its
-    index in them: a layer writes its new rows into the stack and reads
-    through the index, so no step slices a layer's pool out of the
-    stack or builds a second stack.  Donated by the caller, the stacks
-    are updated in place.
-    """
-    if cfg.pos_embed != "rope":
-        raise NotImplementedError(
-            f"paged_decode_step: per-request positions need rope "
-            f"(cfg.pos_embed={cfg.pos_embed!r})")
+def _paged_layers(cfg: ModelConfig, params, cache: Dict, h, apply):
+    """Run the paged layers: the ``first_k`` leading ones one by one, then
+    the scan over periods that carries the pool stacks (each layer writes
+    its rows into the stack and reads it through its index, so no step
+    slices a layer's pool out or builds a second stack).  ``apply(sig, w,
+    h, pools, layer)`` is one layer -> (h, new_pools, routed).  Returns
+    (h, new_cache, counters): ``counters`` holds ``routed_rows``, the MoE
+    assignments computed summed over the layers, for a MoE config only."""
     first_k, period, n_periods = schedule(cfg)
     sigs = layer_sigs(cfg)
-    h = _embed_in(cfg, params, tokens)
+    rr = jnp.zeros((), jnp.int32) if cfg.moe else None
+
+    def add(rr, routed):
+        return rr if routed is None else rr + routed
 
     new0: List = []
     for i in range(first_k):
-        h, nc = apply_layer_paged(cfg, sigs[i], params["layers0"][i], h,
-                                  cache["layers0"][i], block_tables, lens)
+        h, nc, routed = apply(sigs[i], params["layers0"][i], h,
+                              cache["layers0"][i], None)
+        rr = add(rr, routed)
         new0.append(nc)
 
     slot_sigs = [sigs[first_k + s] for s in range(period)]
 
     def body(carry, x):
-        h, pools = carry
+        h, pools, rr = carry
         ws, i = x
         new_pools = []
         for s in range(period):
-            h, nc = apply_layer_paged(cfg, slot_sigs[s], ws[s], h, pools[s],
-                                      block_tables, lens, i)
+            h, nc, routed = apply(slot_sigs[s], ws[s], h, pools[s], i)
+            rr = add(rr, routed)
             new_pools.append(nc)
-        return (h, tuple(new_pools)), None
+        return (h, tuple(new_pools), rr), None
 
-    (h, new_layers), _ = jax.lax.scan(
-        body, (h, cache["layers"]),
+    (h, new_layers, rr), _ = jax.lax.scan(
+        body, (h, cache["layers"], rr),
         (params["layers"], jnp.arange(n_periods, dtype=jnp.int32)))
-    logits = _logits_out(cfg, params, h)
-    return logits, {"layers0": new0, "layers": new_layers}
+    counters = {} if rr is None else {"routed_rows": rr}
+    return h, {"layers0": new0, "layers": new_layers}, counters
+
+
+def paged_decode_step(cfg: ModelConfig, params, cache: Dict,
+                      tokens: jax.Array, block_tables: jax.Array,
+                      lens: jax.Array) -> Tuple[jax.Array, Dict, Dict]:
+    """One continuous-batching decode tick over the block-paged cache.
+
+    tokens (B, 1) int32 — each engine slot's pending token; block_tables
+    (B, NB) int32 logical->physical pool block maps (shared across
+    layers: every layer's pool is indexed by the same table); lens (B,)
+    int32 per-request cache lengths (write index AND RoPE position; 0 for
+    a slot that is not decoding).
+    The cache pytree mirrors :func:`init_cache`'s structure: each
+    ``layers0`` leaf is one layer's (P, KV, page, hd) pool and each
+    ``layers`` leaf the (n_periods, P, KV, page, hd) stack of a period
+    slot's pools (MLA: latent rows, KV = 1) — build it with
+    ``repro.serve.PagedKVCache``.  Unlike :func:`decode_step` there is
+    no batch-wide ``pos``: slots decode at independent offsets, which is
+    what lets one compiled step serve ragged in-flight requests.
+
+    The layer scan carries the pool stacks and hands each layer its
+    index in them (:func:`_paged_layers`).  Donated by the caller, the
+    stacks are updated in place.  Returns (logits, new cache, counters):
+    a MoE config's ``counters["routed_rows"]`` counts the assignments
+    its held experts computed; other configs' are empty.
+    """
+    if cfg.pos_embed != "rope":
+        raise NotImplementedError(
+            f"paged_decode_step: per-request positions need rope "
+            f"(cfg.pos_embed={cfg.pos_embed!r})")
+    h = _embed_in(cfg, params, tokens)
+    h, new_cache, counters = _paged_layers(
+        cfg, params, cache, h,
+        lambda sig, w, h, pools, i: apply_layer_paged(
+            cfg, sig, w, h, pools, block_tables, lens, i))
+    return _logits_out(cfg, params, h), new_cache, counters
 
 
 def paged_prefill_step(cfg: ModelConfig, params, cache: Dict,
                        tokens: jax.Array, block_tables: jax.Array,
                        lens: jax.Array, n_valid: jax.Array, *,
-                       aligned: bool = False) -> Tuple[jax.Array, Dict]:
+                       aligned: bool = False) -> Tuple[jax.Array, Dict, Dict]:
     """One continuation-prefill chunk over the block-paged cache.
 
     tokens (B, C) int32 — a fixed-size chunk of each request's uncached
     prompt suffix, right-padded past ``n_valid``; block_tables (B, NB)
     and lens (B,) as in :func:`paged_decode_step` (``lens`` = tokens
     already cached = the chunk's global start position), and so are the
-    cache pytree and the layer scan that carries its stacks.  Each layer
-    writes the chunk's K/V into the pool and attends back through the
+    cache pytree, the layer scan that carries its stacks and the
+    counters (of the chunk's valid rows).  Each layer writes the chunk's
+    K/V (or latent) rows into the pool and attends back through the
     block table, so a chunk sees both earlier chunks of its own prompt
     AND any prefix blocks *shared* with other requests.  Returns the
     logits at each request's last valid chunk row (B, 1, V) — only
     meaningful for the final chunk, where that row is the last prompt
-    token — plus the updated pool pytree.  Chunking the prompt this way
-    is the incremental-admission path: one fixed compiled shape serves
-    any prompt length, and long prompts interleave with decode ticks
-    instead of stalling them.  ``aligned`` forwards the single-block
-    fast-write promise (B == 1, chunk size divides the page) to the
-    attention layers.
+    token — plus the updated pool pytree and the counters.  Chunking the
+    prompt this way is the incremental-admission path: one fixed
+    compiled shape serves any prompt length, and long prompts interleave
+    with decode ticks instead of stalling them.  ``aligned`` forwards
+    the single-block fast-write promise (B == 1, chunk size divides the
+    page) to the attention layers.
     """
     if cfg.pos_embed != "rope":
         raise NotImplementedError(
             f"paged_prefill_step: per-request positions need rope "
             f"(cfg.pos_embed={cfg.pos_embed!r})")
-    first_k, period, n_periods = schedule(cfg)
-    sigs = layer_sigs(cfg)
     nv = jnp.asarray(n_valid, jnp.int32)
     h = _embed_in(cfg, params, tokens)
-
-    new0: List = []
-    for i in range(first_k):
-        h, nc = apply_layer_prefill_paged(cfg, sigs[i], params["layers0"][i],
-                                          h, cache["layers0"][i],
-                                          block_tables, lens, nv,
-                                          aligned=aligned)
-        new0.append(nc)
-
-    slot_sigs = [sigs[first_k + s] for s in range(period)]
-
-    def body(carry, x):
-        h, pools = carry
-        ws, i = x
-        new_pools = []
-        for s in range(period):
-            h, nc = apply_layer_prefill_paged(cfg, slot_sigs[s], ws[s], h,
-                                              pools[s], block_tables, lens,
-                                              nv, i, aligned=aligned)
-            new_pools.append(nc)
-        return (h, tuple(new_pools)), None
-
-    (h, new_layers), _ = jax.lax.scan(
-        body, (h, cache["layers"]),
-        (params["layers"], jnp.arange(n_periods, dtype=jnp.int32)))
+    h, new_cache, counters = _paged_layers(
+        cfg, params, cache, h,
+        lambda sig, w, h, pools, i: apply_layer_prefill_paged(
+            cfg, sig, w, h, pools, block_tables, lens, nv, i,
+            aligned=aligned))
     # logits only at the last valid row — sliced before the unembed, like
     # prefill's last_pos path, so the (B, C, V) tensor is never formed
     h_last = jnp.take_along_axis(h, (nv - 1)[:, None, None], axis=1)
-    logits = _logits_out(cfg, params, h_last)
-    return logits, {"layers0": new0, "layers": new_layers}
+    return _logits_out(cfg, params, h_last), new_cache, counters

@@ -14,8 +14,12 @@ Expert weights and the (G, E, C, D) buffers shard E over the "expert"
 logical axis (model); the combine gather crossing the expert axis is where
 GSPMD inserts the all-to-all-class collective — the EP communication the
 paper's scoreboard would attribute to the interconnect, and a hillclimb
-target.  Capacity drops follow Switch semantics (first-come within the
-group, position >= C dropped).
+target.  Training (:func:`moe_apply`) drops past a capacity, Switch
+semantics (first-come within the group, position >= C dropped); serving
+(:func:`moe_serve`) is dropless.  A device may hold a share of the
+experts (``MoESpec.n_held`` / ``held_offset``, expert parallelism): the
+router still scores all of them, and only the held experts' part of the
+layer is computed — the other devices' part is theirs to add.
 
 Dual execution path: with ``cfg.use_pallas`` the three expert matmuls
 (gate/up/down projections over the (E, C, D) slot buffers) route through
@@ -43,7 +47,7 @@ from repro.models.config import ModelConfig
 from repro.models.layers import cdtype, dense, mm
 from repro.parallel.api import current_mesh, shard
 
-__all__ = ["init_moe", "moe_apply", "router_topk", "capacity"]
+__all__ = ["init_moe", "moe_apply", "moe_serve", "router_topk", "capacity"]
 
 
 def capacity(cfg: ModelConfig, tokens_per_group: int) -> int:
@@ -55,12 +59,12 @@ def capacity(cfg: ModelConfig, tokens_per_group: int) -> int:
 
 def init_moe(cfg: ModelConfig, key) -> Dict:
     m = cfg.moe
-    D, E, F = cfg.d_model, m.n_experts, m.d_ff_expert
+    D, E, F = cfg.d_model, m.held, m.d_ff_expert
     ks = jax.random.split(key, 6)
     dt = cdtype(cfg)
     s = 1.0 / math.sqrt(D)
     w = {
-        "router": jax.random.normal(ks[0], (D, E), jnp.float32) * s,
+        "router": jax.random.normal(ks[0], (D, m.n_experts), jnp.float32) * s,
         "we_g": jax.random.normal(ks[1], (E, D, F), dt) * s,
         "we_i": jax.random.normal(ks[2], (E, D, F), dt) * s,
         "we_o": jax.random.normal(ks[3], (E, F, D), dt)
@@ -76,16 +80,22 @@ def init_moe(cfg: ModelConfig, key) -> Dict:
     return w
 
 
-def router_topk(cfg: ModelConfig, w_router, x) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Router: f32 softmax over experts, top-k, renormalised gates.
+def router_topk(cfg: ModelConfig, w_router, x, precision=None
+                ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Router: f32 softmax over all ``n_experts``, top-k, gates
+    renormalised to sum to 1 when ``moe.norm_topk`` (else the softmax
+    scores themselves).  ``precision`` is the logits matmul's.
 
-    x: (G, S, D) -> gates (G, S, K) f32, idx (G, S, K) i32, aux_loss scalar.
+    x: (G, S, D) -> gates (G, S, K) f32, idx (G, S, K) i32 (global expert
+    ids), aux_loss scalar.
     """
     m = cfg.moe
-    logits = jnp.einsum("gsd,de->gse", x.astype(jnp.float32), w_router)
+    logits = jnp.einsum("gsd,de->gse", x.astype(jnp.float32),
+                        w_router.astype(jnp.float32), precision=precision)
     probs = jax.nn.softmax(logits, axis=-1)
     gates, idx = jax.lax.top_k(probs, m.top_k)
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    if m.norm_topk:
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
     # Switch-style load-balance aux loss.
     me = jnp.mean(probs, axis=(0, 1))                                # (E,)
     ce = jnp.mean(jax.nn.one_hot(idx, m.n_experts, dtype=jnp.float32),
@@ -94,30 +104,32 @@ def router_topk(cfg: ModelConfig, w_router, x) -> Tuple[jax.Array, jax.Array, ja
     return gates, idx, aux
 
 
-def _slot_maps(cfg: ModelConfig, idx: jax.Array, C: int):
+def _slot_maps(idx: jax.Array, E: int, C: int):
     """Integer slot maps from expert assignments.
 
-    idx: (G, A) expert ids (A = S*K assignments in token order).
+    idx: (G, A) ids among the ``E`` held experts (A = S*K assignments in
+    token order); an id outside [0, E) names an expert held elsewhere.
     Returns:
       pos   (G, A)   position of each assignment within its expert (i32)
-      keep  (G, A)   pos < C and valid
+      keep  (G, A)   held and pos < C
       src   (G, E*C) assignment index feeding each expert slot (0 if empty)
       used  (G, E*C) slot occupancy mask
     """
-    m = cfg.moe
     G, A = idx.shape
-    onehot = jax.nn.one_hot(idx, m.n_experts, dtype=jnp.int32)        # (G,A,E)
+    held = (idx >= 0) & (idx < E)
+    onehot = jax.nn.one_hot(idx, E, dtype=jnp.int32)    # (G,A,E); 0 if not held
     onehot = shard(onehot, "batch", None, "expert")
     pos_all = jnp.cumsum(onehot, axis=1) - 1                          # (G,A,E)
     pos_all = shard(pos_all, "batch", None, "expert")
-    pos = jnp.take_along_axis(pos_all, idx[..., None], axis=-1)[..., 0]
-    keep = pos < C
-    # out-of-capacity assignments scatter out of bounds -> mode="drop"
-    slot = jnp.where(keep, idx * C + pos, m.n_experts * C)
-    src = jnp.zeros((G, m.n_experts * C), jnp.int32)
+    pos = jnp.take_along_axis(pos_all, jnp.clip(idx, 0, E - 1)[..., None],
+                              axis=-1)[..., 0]
+    keep = held & (pos < C)
+    # dropped and absent assignments scatter out of bounds -> mode="drop"
+    slot = jnp.where(keep, idx * C + pos, E * C)
+    src = jnp.zeros((G, E * C), jnp.int32)
     arange = jnp.broadcast_to(jnp.arange(A, dtype=jnp.int32)[None], (G, A))
     src = src.at[jnp.arange(G)[:, None], slot].set(arange, mode="drop")
-    used = jnp.zeros((G, m.n_experts * C), jnp.bool_)
+    used = jnp.zeros((G, E * C), jnp.bool_)
     used = used.at[jnp.arange(G)[:, None], slot].set(True, mode="drop")
     return pos, keep, shard(src, "batch", "expert"), \
         shard(used, "batch", "expert")
@@ -153,27 +165,26 @@ def _expert_mm(x4: jax.Array, w3: jax.Array, *, use_pallas: bool,
     return mm("beck,ekn->becn", x4, w3, out_dtype=out_dtype)
 
 
-def moe_apply(cfg: ModelConfig, w, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """x: (B, S, D) -> (y, aux_loss).  Groups = batch rows."""
+def _experts(cfg: ModelConfig, w, x: jax.Array, gates: jax.Array,
+             idx: jax.Array, C: int, valid=None) -> Tuple[jax.Array, jax.Array]:
+    """Dispatch, the held experts' SwiGLU, combine, plus the shared
+    experts.  x (G, S, D); gates/idx (G, S, K) from :func:`router_topk`;
+    ``valid`` (G, S) marks the rows whose assignments count (the others
+    reach no expert).  Returns (y (G, S, D), keep (G, S*K): the
+    assignments computed)."""
     m = cfg.moe
-    B, S, D = x.shape
-    E, K = m.n_experts, m.top_k
-    C = capacity(cfg, S)
-    # pin x's sharding with D on the model axis: D is the PASSTHROUGH dim
-    # of the dispatch/combine gathers, so GSPMD partitions them AND their
-    # backward scatter-adds (S-sharding would leave unsharded (B,S,D) f32
-    # gradient scatters — the gathered dim can't partition vs indices)
-    x = shard(x, "batch", None, "tp")
-    gates, idx, aux = router_topk(cfg, w["router"], x)
-
-    idx_flat = idx.reshape(B, S * K)                   # assignment order: (t, k)
-    pos, keep, src, used = _slot_maps(cfg, idx_flat, C)
+    G, S, D = x.shape
+    E, K = m.held, m.top_k
+    idx_flat = idx.reshape(G, S * K) - m.held_offset   # (t, k) order, local
+    if valid is not None:
+        idx_flat = jnp.where(jnp.repeat(valid, K, axis=1), idx_flat, E)
+    pos, keep, src, used = _slot_maps(idx_flat, E, C)
 
     # token index of each assignment; gather tokens into expert slot buffers
-    tok_of_src = src // K                                             # (B, E*C)
-    xbuf = jnp.take_along_axis(x, tok_of_src[..., None], axis=1)      # (B,E*C,D)
+    tok_of_src = src // K                                             # (G, E*C)
+    xbuf = jnp.take_along_axis(x, tok_of_src[..., None], axis=1)      # (G,E*C,D)
     xbuf = xbuf * used[..., None].astype(x.dtype)
-    xbuf = xbuf.reshape(B, E, C, D)
+    xbuf = xbuf.reshape(G, E, C, D)
     xbuf = shard(xbuf, "batch", "expert", None, None)
 
     # expert FFN (E-sharded batched einsum, or the moe_gmm grouped-GEMM
@@ -194,16 +205,52 @@ def moe_apply(cfg: ModelConfig, w, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
     ybuf = shard(ybuf, "batch", None, None, "tp")
 
     # combine: gather each kept assignment's slot output, weight, sum over k
-    slot = jnp.where(keep, idx_flat * C + pos, 0)                     # (B,S*K)
-    y_k = jnp.take_along_axis(ybuf.reshape(B, E * C, D), slot[..., None],
-                              axis=1)                                 # (B,S*K,D)
+    slot = jnp.where(keep, idx_flat * C + pos, 0)                     # (G,S*K)
+    y_k = jnp.take_along_axis(ybuf.reshape(G, E * C, D), slot[..., None],
+                              axis=1)                                 # (G,S*K,D)
     y_k = shard(y_k, "batch", None, "tp")
-    gk = (gates.reshape(B, S * K) * keep.astype(jnp.float32)).astype(x.dtype)
-    y = jnp.einsum("bad,ba->bad", y_k, gk).reshape(B, S, K, D).sum(axis=2)
+    gk = (gates.reshape(G, S * K) * keep.astype(jnp.float32)).astype(x.dtype)
+    y = jnp.einsum("bad,ba->bad", y_k, gk).reshape(G, S, K, D).sum(axis=2)
     y = shard(y, "batch", "seq", None)
 
     if m.n_shared:
         ws = w["shared"]
         hs = jax.nn.silu(dense(x, ws["wg"])) * dense(x, ws["wi"])
         y = y + dense(hs, ws["wo"])
-    return y, aux * m.router_aux_weight
+    return y, keep
+
+
+def moe_apply(cfg: ModelConfig, w, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """Training: x (B, S, D) -> (y, aux_loss).  Groups = batch rows, each
+    expert takes at most ``capacity`` rows of a group (Switch drops)."""
+    # pin x's sharding with D on the model axis: D is the PASSTHROUGH dim
+    # of the dispatch/combine gathers, so GSPMD partitions them AND their
+    # backward scatter-adds (S-sharding would leave unsharded (B,S,D) f32
+    # gradient scatters — the gathered dim can't partition vs indices)
+    x = shard(x, "batch", None, "tp")
+    gates, idx, aux = router_topk(cfg, w["router"], x)
+    y, _ = _experts(cfg, w, x, gates, idx, capacity(cfg, x.shape[1]))
+    return y, aux * cfg.moe.router_aux_weight
+
+
+def moe_serve(cfg: ModelConfig, w, x: jax.Array,
+              valid=None) -> Tuple[jax.Array, jax.Array]:
+    """Serving: x (B, S, D) -> (y, routed_rows), dropless.
+
+    The router scores every expert; only assignments to the held experts
+    are computed, and none is dropped: all B*S tokens form one group, so
+    an expert's buffer holds every row that can reach it (C = B*S; a
+    token picks an expert at most once).  ``valid`` (B, S) leaves the
+    other rows (chunk padding, idle slots) out of every expert.
+    ``routed_rows`` (int32) counts the assignments computed, the work
+    the padded buffers carry."""
+    B, S, D = x.shape
+    xt = x.reshape(1, B * S, D)
+    # full f32 logits, as the published gate computes them: the top-k
+    # choice at near ties decides which experts a token reaches
+    gates, idx, _ = router_topk(cfg, w["router"], xt,
+                                precision=jax.lax.Precision.HIGHEST)
+    v = None if valid is None else jnp.broadcast_to(valid, (B, S)).reshape(
+        1, B * S)
+    y, keep = _experts(cfg, w, xt, gates, idx, B * S, v)
+    return y.reshape(B, S, D), jnp.sum(keep, dtype=jnp.int32)

@@ -2,8 +2,11 @@
 
 Cache layout
 ------------
-Every attention layer owns two pools ``k``/``v`` of shape
-``(P, KV, page, hd)``: ``P`` physical blocks of ``page`` token rows,
+Every GQA attention layer owns two pools ``k``/``v`` of shape
+``(P, KV, page, hd)`` (an MLA layer a ``ckv`` pool of latent rows,
+``(P, 1, page, kv_lora_rank)``, and a ``kpe`` pool of rope keys stored
+transposed, ``(P, 1, qk_rope_dim, page)``; read as one key head whose
+leading columns are the values): ``P`` physical blocks of ``page`` token rows,
 head-major inside a block so each head's page is one contiguous
 ``(page, hd)`` slab the TPU kernel can DMA as a tile; the pools of
 the layers the model scans are stacked, ``(n_periods, P, KV, page,
@@ -61,11 +64,20 @@ from repro.models.blocks import layer_sigs, schedule
 from repro.models.config import ModelConfig
 from repro.models.layers import cdtype
 
-__all__ = ["PagedKVCache", "default_page_size", "prefix_digests"]
+__all__ = ["PagedKVCache", "default_page_size", "init_pools", "pool_heads",
+           "prefix_digests"]
 
 #: T the page-size probe plans for: the planner cap, so the chosen page
 #: is the largest aligned block the device's VMEM budget admits.
 _PROBE_T = 512
+
+
+def pool_heads(cfg: ModelConfig):
+    """(KV heads, row width) of one layer's pool: GQA's K and V heads, or
+    MLA's latent rows (c_kv | roped key) as one head."""
+    if cfg.mla:
+        return 1, cfg.mla.kv_lora_rank + cfg.mla.qk_rope_dim
+    return cfg.n_kv_heads, cfg.hd
 
 
 def default_page_size(cfg: ModelConfig, device=None, *,
@@ -78,9 +90,10 @@ def default_page_size(cfg: ModelConfig, device=None, *,
     decode tick gather and attend over rows that can never hold data.
     """
     probe_t = _PROBE_T if cap is None else min(_PROBE_T, max(1, cap))
+    kv, width = pool_heads(cfg)
     plan = plan_for("paged_decode_attention",
                     {"B": 1, "T": probe_t, "H": cfg.n_heads,
-                     "KV": cfg.n_kv_heads, "hd": cfg.hd},
+                     "KV": kv, "hd": width},
                     dtype=cfg.dtype, device=device)
     return plan.blocks["block_kv"]
 
@@ -102,6 +115,35 @@ def prefix_digests(tokens: np.ndarray, page: int) -> List[bytes]:
     return out
 
 
+def init_pools(cfg: ModelConfig, n_blocks: int, page: int) -> Dict:
+    """The zeroed pool pytree: ``layers0`` one pool per leading layer,
+    ``layers`` one (n_periods, ...) stack per period slot.  A GQA pool is
+    ``{"k", "v"}`` of (P, KV, page, hd); an MLA pool ``{"ckv", "kpe"}``:
+    each token's normed latent c_kv, (P, 1, page, kv_lora_rank), and its
+    roped key stored transposed, (P, 1, qk_rope_dim, page), so neither
+    pool pads its minor dim to the TPU's 128 lanes (at DeepSeek-V2 widths
+    576 bf16 values a token a layer, 31,104 B over 27 layers)."""
+    dt = cdtype(cfg)
+    kv, width = pool_heads(cfg)
+    first_k, period, n_periods = schedule(cfg)
+
+    def pool():
+        if cfg.mla:
+            m = cfg.mla
+            return {"ckv": jnp.zeros((n_blocks, 1, page, m.kv_lora_rank), dt),
+                    "kpe": jnp.zeros((n_blocks, 1, m.qk_rope_dim, page), dt)}
+        shp = (n_blocks, kv, page, width)
+        return {"k": jnp.zeros(shp, dt), "v": jnp.zeros(shp, dt)}
+
+    return {
+        "layers0": [pool() for _ in range(first_k)],
+        "layers": tuple(
+            jax.tree.map(lambda a: jnp.zeros((n_periods,) + a.shape,
+                                             a.dtype), pool())
+            for _ in range(period)),
+    }
+
+
 class PagedKVCache:
     """Pool pytree + refcounting allocator for one model's KV blocks.
 
@@ -120,25 +162,25 @@ class PagedKVCache:
         sigs = layer_sigs(cfg)
         bad = [f"layer {i}: {s[0]}" for i, s in enumerate(sigs)
                if s[0] != "attn"]
-        if cfg.mla:
-            bad.append("mla latent cache")
         if bad:
             raise NotImplementedError(
-                "PagedKVCache: only plain GQA attention layers page "
-                f"(config {cfg.name!r} has {', '.join(bad)})")
+                "PagedKVCache: only attention layers page (GQA K/V or "
+                "MLA latent rows); SSM state and cross-attention caches "
+                f"do not (config {cfg.name!r} has {', '.join(bad)})")
         if page is None:
             page = default_page_size(cfg, device)
         else:
             # pinning block_kv re-runs the tiling contract: a misaligned
             # page raises here, not inside the first decode step
+            kv, width = pool_heads(cfg)
             plan_for("paged_decode_attention",
                      {"B": 1, "T": page, "H": cfg.n_heads,
-                      "KV": cfg.n_kv_heads, "hd": cfg.hd, "page": page},
+                      "KV": kv, "hd": width, "page": page},
                      dtype=cfg.dtype, device=device)
         self.cfg = cfg
         self.page = int(page)
         self.n_blocks = int(n_blocks)
-        self.pools = self._init_pools(cfg)
+        self.pools = init_pools(cfg, self.n_blocks, self.page)
         self._refs: List[int] = [0] * self.n_blocks
         # LIFO stack of never-registered writable blocks
         self._fresh: List[int] = list(range(self.n_blocks - 1, 0, -1))
@@ -147,22 +189,6 @@ class PagedKVCache:
         self._parked: Dict[int, None] = {}
         self._index: Dict[bytes, int] = {}      # digest -> block
         self._digest: Dict[int, bytes] = {}     # block  -> digest
-
-    def _init_pools(self, cfg: ModelConfig) -> Dict:
-        dt = cdtype(cfg)
-        shp = (self.n_blocks, cfg.n_kv_heads, self.page, cfg.hd)
-        first_k, period, n_periods = schedule(cfg)
-
-        def pool():
-            return {"k": jnp.zeros(shp, dt), "v": jnp.zeros(shp, dt)}
-
-        return {
-            "layers0": [pool() for _ in range(first_k)],
-            "layers": tuple(
-                jax.tree.map(lambda a: jnp.zeros((n_periods,) + a.shape,
-                                                 a.dtype), pool())
-                for _ in range(period)),
-        }
 
     # -- allocator ---------------------------------------------------------
 

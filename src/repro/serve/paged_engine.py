@@ -81,10 +81,16 @@ are the tick's counts::
       serve.control        steps 1-4: cancelled, timed_out, shed
       serve.admit          step 5: admitted, prefix_blocks
       serve.prefill        one chunk of step 6: req, slot, start, n_valid
+                           (+ routed_rows, MoE configs)
         serve.prefill.wait   the wait for that chunk
       serve.grow           step 7a: grown, preempted
       serve.decode         step 7b: active (slots), kv_rows (sum of lens + 1)
+                           (+ routed_rows, MoE configs)
         serve.decode.wait    the wait for that step
+
+``routed_rows`` is the MoE assignments the call's held experts computed,
+summed over its layers (valid rows only); the step returns it beside the
+tokens and it is read after the same wait.
       serve.check          the invariant checks, when on
 """
 
@@ -108,9 +114,43 @@ from repro.serve.resilience import (CANCELLED, OK, PREEMPTED, SHED, TIMEOUT,
                                     AdmissionPolicy, FaultPlan,
                                     QueueCapPolicy, queue_entries)
 
-__all__ = ["PagedServeEngine", "Request", "RequestResult"]
+__all__ = ["PagedServeEngine", "Request", "RequestResult", "serve_steps"]
 
 span = jax.profiler.TraceAnnotation          # see "Spans" above
+
+
+def serve_steps(cfg: ModelConfig, *, aligned: bool):
+    """The engine's two jitted programs, ``(decode, prefill)``:
+    ``decode(params, pools, tokens (B, 1), tables, lens)`` and
+    ``prefill(params, pools, tokens (1, C), tables (1, NB), start (1,),
+    n_valid (1,))``, each returning ``(logits, greedy tokens, pools)``
+    and, for a MoE config only, ``counters`` after them: its
+    ``routed_rows``, which the call's spans carry.  Greedy tokens are computed
+    in-graph: the scheduler's hot loop transfers (B,) ints per tick, not
+    (B, V) logits + eager ops.
+
+    The pool pytree is donated: run() threads one live pools value
+    through every dispatch and never reads a superseded one, so a step's
+    output reuses the input's buffers.  The rows themselves are written
+    in place by the layer scan, which carries the pool stacks and writes
+    each layer's new rows at its index; donation only spares the copy of
+    the input.  ONE compiled prefill serves every prompt: fixed (1, C)
+    tokens against the full table width, the ragged final chunk padded
+    and masked via ``n_valid`` instead of recompiling."""
+
+    def _step(p, c, t, tbl, ln):
+        logits, new_c, counters = paged_decode_step(cfg, p, c, t, tbl, ln)
+        toks = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+        return (logits, toks, new_c) + ((counters,) if counters else ())
+
+    def _pstep(p, c, t, tbl, ln, nv):
+        logits, new_c, counters = paged_prefill_step(
+            cfg, p, c, t, tbl, ln, nv, aligned=aligned)
+        toks = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+        return (logits, toks, new_c) + ((counters,) if counters else ())
+
+    return (jax.jit(_step, donate_argnums=(1,)),
+            jax.jit(_pstep, donate_argnums=(1,)))
 
 
 @dataclasses.dataclass
@@ -185,39 +225,12 @@ class PagedServeEngine:
         self.cache = PagedKVCache(cfg, n_blocks=n_blocks, page=self.page,
                                   device=device)
 
-        def _step(p, c, t, tbl, ln):
-            # greedy tokens computed in-graph: the scheduler's hot loop
-            # transfers (B,) ints per tick, not (B, V) logits + eager ops
-            logits, new_c = paged_decode_step(cfg, p, c, t, tbl, ln)
-            toks = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-            return logits, toks, new_c
-
-        # the pool pytree is donated: run() threads one live pools value
-        # through every dispatch and never reads a superseded one, so the
-        # step's output reuses the input's buffers.  The rows themselves
-        # are written in place by the layer scan in paged_decode_step,
-        # which carries the pool stacks and writes each layer's new rows
-        # at its index; donation only spares the copy of the input
-        self._decode = jax.jit(_step, donate_argnums=(1,))
-
         # chunks start at multiples of prefill_chunk past a page boundary
         # (prefix matches are page-aligned), so when the chunk size
         # divides the page no chunk ever crosses a block boundary and the
         # pool write collapses to one contiguous slice (aligned=True)
-        aligned = self.page % self.prefill_chunk == 0
-
-        def _pstep(p, c, t, tbl, ln, nv):
-            logits, new_c = paged_prefill_step(cfg, p, c, t, tbl, ln, nv,
-                                               aligned=aligned)
-            toks = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-            return logits, toks, new_c
-
-        # ONE compiled prefill: fixed (1, prefill_chunk) tokens against
-        # the full table width, whatever the prompt length — the ragged
-        # final chunk pads and masks via ``nv`` instead of recompiling.
-        # Pools donated as for _decode; paged_prefill_step writes the
-        # chunk's rows in place the same way.
-        self._prefill = jax.jit(_pstep, donate_argnums=(1,))
+        self._decode, self._prefill = serve_steps(
+            cfg, aligned=self.page % self.prefill_chunk == 0)
 
     def _prompt_blocks(self, s: int) -> int:
         """Blocks the prompt itself occupies (>= 1); decode rows are
@@ -546,19 +559,24 @@ class PagedServeEngine:
                     pos = slot.filled
                     nv = min(C, s - pos)
                     with span("serve.prefill", req=slot.req, slot=si,
-                              start=pos, n_valid=nv):
+                              start=pos, n_valid=nv) as sp:
                         toks = np.zeros((1, C), np.int32)
                         toks[0, :nv] = r.prompt[pos:pos + nv]
                         # jnp.array (not asarray): don't alias scheduler
                         # state the async dispatch would race with (same
                         # rationale as decode)
-                        logits, greedy, pools = self._prefill(
+                        logits, greedy, pools, *counters = self._prefill(
                             self.params, pools, jnp.array(toks),
                             jnp.array(tables[si:si + 1]),
                             jnp.array([pos], np.int32),
                             jnp.array([nv], np.int32))
                         with span("serve.prefill.wait"):
-                            jax.block_until_ready((logits, greedy, pools))
+                            jax.block_until_ready((logits, greedy, pools,
+                                                   counters))
+                        if counters:
+                            sp.set_metadata(**{k: int(v) for k, v in
+                                               jax.device_get(counters[0])
+                                               .items()})
                         prefill_chunks += 1
                         slot.filled = pos + nv
                         if self.prefix_cache:
@@ -622,7 +640,7 @@ class PagedServeEngine:
                 # rows the step attends, sum(lens[active] + 1), is one sum
                 if active:
                     with span("serve.decode", active=len(active),
-                              kv_rows=int(lens.sum()) + len(active)):
+                              kv_rows=int(lens.sum()) + len(active)) as sp:
                         # jnp.array (not asarray): asarray zero-copies
                         # numpy on CPU, so the async decode would alias
                         # these host buffers while the scheduler keeps
@@ -638,7 +656,7 @@ class PagedServeEngine:
                         for si in range(B):
                             if slots[si] is not None and lens[si] == 0:
                                 dec_tables[si] = 0  # scatter to null block
-                        logits, greedy, pools = self._decode(
+                        logits, greedy, pools, *counters = self._decode(
                             self.params, pools, jnp.array(pend[:, None]),
                             jnp.array(dec_tables), jnp.array(lens))
                         # materialize the whole tick before dispatching
@@ -653,7 +671,12 @@ class PagedServeEngine:
                         # computation from run() is ever still in flight
                         # when the caller's next one starts.
                         with span("serve.decode.wait"):
-                            jax.block_until_ready((logits, greedy, pools))
+                            jax.block_until_ready((logits, greedy, pools,
+                                                   counters))
+                        if counters:
+                            sp.set_metadata(**{k: int(v) for k, v in
+                                               jax.device_get(counters[0])
+                                               .items()})
                         decode_steps += 1
                         lens[active] += 1
                         keys = None

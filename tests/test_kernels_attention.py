@@ -265,6 +265,53 @@ def test_paged_decode_reads_a_layer_of_the_stack_in_place(dt):
                                    rtol=2e-2, atol=2e-2)
 
 
+@pytest.mark.parametrize("dt", [jnp.float32, jnp.bfloat16])
+def test_paged_decode_latent_mode(dt):
+    """Latent mode (absorbed multi-head latent attention): one KV head
+    whose keys are 128-wide latent rows followed by a 64-wide rope key
+    kept transposed in a pool of its own, the values the latent rows
+    themselves; 8 query heads, a custom scale; ragged last pages, a
+    stacked pool read at a layer, null-block tails — against the gather
+    oracle."""
+    L, B, NB, page, H, R, rope = 2, 3, 3, 128, 8, 128, 64
+    rng = np.random.RandomState(7)
+    P = B * NB + 1
+    q = jnp.asarray(rng.randn(B, H, R + rope), dt)
+    ckv = jnp.asarray(rng.randn(L, P, 1, page, R), dt)
+    kpe = jnp.asarray(rng.randn(L, P, 1, rope, page), dt)
+    lens = np.asarray([1, 130, 3 * page - 5], np.int32)
+    blocks = iter(rng.permutation(np.arange(1, P)))
+    tables = np.zeros((B, NB), np.int32)         # tails: the null block
+    for b in range(B):
+        for j in range(-(-int(lens[b]) // page)):
+            tables[b, j] = next(blocks)
+    tables, kv_len = jnp.asarray(tables), jnp.asarray(lens)
+    scale = 1.59 / np.sqrt(R + rope)
+    tol = 1e-4 if dt == jnp.float32 else 2e-2
+    for i in range(L):
+        y = ops.paged_decode_attention(q, ckv, None, tables, kv_len,
+                                       jnp.int32(i), k_rope_pool=kpe,
+                                       scale=scale)
+        assert y.shape == (B, H, R)
+        yr = ref.paged_decode_attention_ref(q, ckv[i], None, tables, kv_len,
+                                            k_rope_pool=kpe[i], scale=scale)
+        np.testing.assert_allclose(np.asarray(y, np.float32),
+                                   np.asarray(yr, np.float32),
+                                   rtol=tol, atol=tol)
+    # the rope keys count: zeroing them moves the result as the oracle
+    # says, so the kernel neither ignores nor misplaces them
+    y0 = ops.paged_decode_attention(q, ckv, None, tables, kv_len,
+                                    jnp.int32(1), k_rope_pool=kpe * 0,
+                                    scale=scale)
+    yr0 = ref.paged_decode_attention_ref(q, ckv[1], None, tables, kv_len,
+                                         k_rope_pool=kpe[1] * 0, scale=scale)
+    np.testing.assert_allclose(np.asarray(y0, np.float32),
+                               np.asarray(yr0, np.float32), rtol=tol,
+                               atol=tol)
+    assert np.abs(np.asarray(y0, np.float32) - np.asarray(y, np.float32)
+                  ).max() > 10 * tol
+
+
 def test_paged_decode_page_block_mismatch_raises():
     """A plan whose block_kv != the pool page is a geometry bug: raise."""
     import pytest

@@ -87,7 +87,8 @@ def test_cache_pool_shapes_mirror_init_cache():
 
 def test_cache_rejects_non_attention_layers():
     mamba = get_config("mamba2-370m").reduced()
-    with pytest.raises(NotImplementedError, match="only plain GQA"):
+    with pytest.raises(NotImplementedError,
+                       match="only attention layers page.*SSM state"):
         PagedKVCache(mamba, n_blocks=3, page=PAGE)
 
 

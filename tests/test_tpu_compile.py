@@ -77,6 +77,14 @@ def _cases():
                 q, k, v, t, n, i, **kw),
             [((B, H, hd), bf), ((4,) + pool, bf), ((4,) + pool, bf),
              ((B, T // page), i32), ((B,), i32), ((), i32)]),
+        # latent mode at DeepSeek-V2 widths: 16 heads over one head of
+        # 512-wide latent rows plus 64-wide rope keys kept transposed
+        "paged_decode_attention_latent": (
+            lambda q, c, r, t, n, i: ops.paged_decode_attention(
+                q, c, None, t, n, i, k_rope_pool=r, scale=0.1147, **kw),
+            [((16, 16, 576), bf), ((4, 33, 1, page, 512), bf),
+             ((4, 33, 1, 64, page), bf), ((16, 2), i32), ((16,), i32),
+             ((), i32)]),
         "mfma_gemm": (
             lambda a, b, c: ops.mfma_gemm(a, b, c, **kw),
             [((S, q7.d_model), bf), ((q7.d_model, q7.d_ff), bf),
@@ -96,10 +104,78 @@ def _cases():
 @pytest.mark.parametrize("kernel", ["flash_attention", "decode_attention",
                                     "paged_decode_attention",
                                     "paged_decode_attention_stacked",
+                                    "paged_decode_attention_latent",
                                     "mfma_gemm", "moe_gmm", "mamba2_ssd"])
 def test_kernel_compiles_for_v5e(kernel, one_chip):
     fn, specs = _cases()[kernel]
     args = [jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
             for shape, dt in specs]
     compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill"])
+def test_deepseek_v2_lite_share_steps_fit_one_v5e(step, one_chip,
+                                                  monkeypatch):
+    """The engine's decode and prefill programs for DeepSeek-V2-Lite's
+    one-chip expert share (all 27 layers, 8 of 64 experts, 16 slots x
+    16384 rows, 513 blocks of 512) compile for a described v5e: the
+    weights, the latent pools and the step's temporaries fit the chip,
+    the pools are donated (aliased to the outputs), and no temporary is
+    pool-sized — the layer scan writes and reads the pool stacks in
+    place.  Both kernels are in the programs."""
+    import dataclasses
+
+    from repro.kernels import compat, dispatch
+    from repro.models.model import init_params
+    from repro.serve.paged_cache import init_pools
+    from repro.serve.paged_engine import serve_steps
+    # compile the programs the chip runs: bf16 dots, kernels not
+    # interpreted (the CPU backend would pick both otherwise)
+    monkeypatch.setenv("REPRO_CPU_F32_DOTS", "0")
+    monkeypatch.setattr(compat, "default_interpret", lambda: False)
+    base = get_config("deepseek-v2-lite-16b")
+    cfg = dataclasses.replace(
+        base, use_pallas=True, pallas_device=_DEVICE,
+        moe=dataclasses.replace(base.moe, n_held=8, held_offset=0))
+    B, max_len, page = 16, 16384, 512
+    NB = max_len // page
+    P = B * NB + 1
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(lambda k: init_params(cfg, k),
+                                    jax.random.PRNGKey(0)))
+    pools = on_chip(jax.eval_shape(lambda: init_pools(cfg, P, page)))
+    pool_bytes = sum(a.size * 2 for a in jax.tree.leaves(pools))
+    assert pool_bytes == P * 27 * page * 576 * 2          # 8.17 GB
+    i32 = jnp.int32
+    decode, prefill = serve_steps(cfg, aligned=True)
+    if step == "decode":
+        fn, args = decode, (jax.ShapeDtypeStruct((B, 1), i32),
+                            jax.ShapeDtypeStruct((B, NB), i32),
+                            jax.ShapeDtypeStruct((B,), i32))
+    else:
+        fn, args = prefill, (jax.ShapeDtypeStruct((1, 512), i32),
+                             jax.ShapeDtypeStruct((1, NB), i32),
+                             jax.ShapeDtypeStruct((1,), i32),
+                             jax.ShapeDtypeStruct((1,), i32))
+    args = tuple(jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+                 for a in args)
+    with dispatch.decision_scope() as decs:
+        lowered = fn.lower(params, pools, *args)
+    assert decs["moe_gmm"].use_kernel
+    if step == "decode":
+        assert decs["paged_decode_attention"].use_kernel
+    compiled = lowered.compile()
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= pool_bytes          # donated in place
+    assert ma.temp_size_in_bytes < pool_bytes / 20       # no pool copy
+    # weights 6.22 GB + pools 8.17 GB + temporaries under the ~15.75 GiB
+    # the TPU compiler gives a program on one 16 GiB v5e
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
+    assert total < 15.75 * 2**30
     assert "tpu_custom_call" in compiled.as_text()
