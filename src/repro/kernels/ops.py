@@ -238,7 +238,8 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, kv_len,
     The pool's page size IS the kv tile, so the plan's ``block_kv`` must
     equal it — the ``shapes["page"]`` pin makes the planner agree on
     every device; there is no ``pad=`` mode (pool geometry is aligned by
-    construction via :class:`~repro.serve.PagedKVCache`).
+    construction via :class:`~repro.serve.PagedKVCache`).  The plan's
+    ``kv_step`` sets how many KV heads of a page one grid step reads.
     """
     B, H, hd = q.shape
     KV, page = k_pool.shape[-3], k_pool.shape[-2]
@@ -256,6 +257,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, kv_len,
     return _da.paged_decode_attention(
         q, k_pool, v_pool, block_tables, kv_len, layer,
         k_rope_pool=k_rope_pool, scale=float(scale or 0.0),
+        kv_step=blocks["kv_step"],
         interpret=compat.resolve_interpret(interpret))
 
 

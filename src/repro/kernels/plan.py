@@ -184,9 +184,9 @@ class TilePlan:
 
     ``blocks`` holds exactly the keyword arguments the ops-layer wrapper
     forwards to the kernel (``block_m``/``block_n``/``block_k``,
-    ``block_q``/``block_kv``, ``chunk``); the perf engines record the
-    same mapping in ``Report.plan`` so predicted and executed tiles can
-    be cross-checked.
+    ``block_q``/``block_kv``, ``chunk``, ``kv_step``); the perf engines
+    record the same mapping in ``Report.plan`` so predicted and executed
+    tiles can be cross-checked.
     """
 
     kernel: str
@@ -236,6 +236,7 @@ class _Dim:
     size: int
     sublane: bool = False        # sublane- instead of MXU-quantised
     depth: bool = False          # contraction dim (full-depth step legal)
+    heads: bool = False          # a head count: any divisor is a block
 
 
 def _pad_to(dim: int, quantum: int) -> int:
@@ -245,7 +246,7 @@ def _pad_to(dim: int, quantum: int) -> int:
 def _candidates(kernel: str, d: _Dim, *, align: int,
                 pad: bool) -> Tuple[int, Sequence[int]]:
     """(possibly padded size, descending quantum-aligned divisor blocks)."""
-    q = SUBLANE if d.sublane else align
+    q = 1 if d.heads else SUBLANE if d.sublane else align
     size = _pad_to(d.size, q) if pad else d.size
     if size % q:
         if d.depth:
@@ -306,16 +307,17 @@ def _plan(kernel: str, spec: DeviceSpec, dtype, *,
     validate_tiling(
         kernel,
         {d.dim_name: (sizes[d.dim_name], chosen[d.block_name])
-         for d in dims if not d.sublane},
+         for d in dims if not (d.sublane or d.heads)},
         align=align,
         depth_dims=tuple(d.dim_name for d in dims if d.depth),
         block_names={d.dim_name: d.block_name for d in dims})
-    validate_tiling(
-        kernel,
-        {d.dim_name: (sizes[d.dim_name], chosen[d.block_name])
-         for d in dims if d.sublane},
-        align=align, depth_dims=(), quantum=SUBLANE,
-        block_names={d.dim_name: d.block_name for d in dims})
+    for quantum, pick in ((SUBLANE, "sublane"), (1, "heads")):
+        validate_tiling(
+            kernel,
+            {d.dim_name: (sizes[d.dim_name], chosen[d.block_name])
+             for d in dims if getattr(d, pick)},
+            align=align, depth_dims=(), quantum=quantum,
+            block_names={d.dim_name: d.block_name for d in dims})
 
     return TilePlan(kernel=kernel, device=spec.name, dtype=str(dtype),
                     blocks=dict(chosen), grid=grid(sizes, chosen),
@@ -374,12 +376,13 @@ def _plan_flash_attention(shapes, dtype, spec, overrides, pad):
         overrides=overrides, pad=pad)
 
 
-def _plan_decode_attention(shapes, dtype, spec, overrides, pad):
+def _plan_decode_attention(shapes, dtype, spec, overrides, pad,
+                           kernel="decode_attention"):
     B, T = shapes["B"], shapes["T"]
     H, KV, hd = shapes["H"], shapes["KV"], shapes["hd"]
     G = H // KV
     return _plan(
-        "decode_attention", spec, dtype,
+        kernel, spec, dtype,
         dims=(_Dim("block_kv", "T", T),),
         caps={"block_kv": 512},
         footprint=lambda b, dsz: (2 * G * hd * dsz
@@ -390,27 +393,37 @@ def _plan_decode_attention(shapes, dtype, spec, overrides, pad):
 
 
 def _plan_paged_decode_attention(shapes, dtype, spec, overrides, pad):
-    """Same per-step geometry as ``decode_attention`` — one (G, block_kv)
-    score tile and (m, l, acc) scratch — but ``block_kv`` doubles as the
-    KV-pool page size.  An optional ``shapes["page"]`` pins ``block_kv``
-    to an existing pool's page so plans always match pool geometry on
-    every device; omit it (the :class:`~repro.serve.PagedKVCache`
-    constructor does) to let the planner choose the page size."""
+    """Two tile dims.  ``block_kv`` doubles as the KV-pool page size and
+    is chosen first, as ``decode_attention`` chooses it for one head (a
+    (G, block_kv) score tile, one K and one V page), so the page never
+    depends on the heads.  An optional
+    ``shapes["page"]`` pins it to an existing pool's page so plans always
+    match pool geometry on every device; omit it (the
+    :class:`~repro.serve.PagedKVCache` constructor does) to let the
+    planner choose the page size.  Then ``kv_step``, the KV heads one
+    grid step reads: the largest divisor of KV whose step — q/o tiles,
+    the K and V pages double-buffered, f32 (m, l, acc) scratch — fits
+    the budget."""
     B, T = shapes["B"], shapes["T"]
     H, KV, hd = shapes["H"], shapes["KV"], shapes["hd"]
     G = H // KV
     page = shapes.get("page")
     if page is not None and overrides.get("block_kv") is None:
         overrides = dict(overrides, block_kv=int(page))
+    if overrides.get("block_kv") is None:
+        one_head = _plan_decode_attention(shapes, dtype, spec, {}, pad,
+                                          kernel="paged_decode_attention")
+        overrides = dict(overrides, block_kv=one_head.blocks["block_kv"])
     return _plan(
         "paged_decode_attention", spec, dtype,
-        dims=(_Dim("block_kv", "T", T),),
-        caps={"block_kv": 512},
-        # q/o tiles + one K and one V page + f32 (m, l, acc) scratch.
-        footprint=lambda b, dsz: (2 * G * hd * dsz
-                                  + 2 * b["block_kv"] * hd * dsz
-                                  + G * (hd + 2) * 4),
-        grid=lambda s, b: (B * KV, s["T"] // b["block_kv"]),
+        dims=(_Dim("block_kv", "T", T),
+              _Dim("kv_step", "KV", KV, heads=True)),
+        caps={"block_kv": 512, "kv_step": KV},
+        footprint=lambda b, dsz: b["kv_step"] * (
+            2 * G * hd * dsz + 4 * b["block_kv"] * hd * dsz
+            + G * (hd + 2) * 4),
+        grid=lambda s, b: (B * KV // b["kv_step"],
+                           s["T"] // b["block_kv"]),
         overrides=overrides, pad=pad)
 
 
@@ -565,7 +578,7 @@ for _entry in (
         op="repro.kernels.ops:paged_decode_attention",
         ref="repro.kernels.ref:paged_decode_attention_ref",
         planner=_plan_paged_decode_attention,
-        block_names=("block_kv",),
+        block_names=("block_kv", "kv_step"),
         doc="flash-decode over a block-paged KV pool via a block table"),
     KernelEntry(
         name="mamba2_ssd", op="repro.kernels.ops:mamba2_ssd",

@@ -84,7 +84,9 @@ are the tick's counts::
                            (+ routed_rows, MoE configs)
         serve.prefill.wait   the wait for that chunk
       serve.grow           step 7a: grown, preempted
-      serve.decode         step 7b: active (slots), kv_rows (sum of lens + 1)
+      serve.decode         step 7b: active (slots), kv_rows (sum of lens + 1),
+                           kv_pages (pages the attention kernel fetches a
+                           layer: ceil((lens + 1) / page) over all slots)
                            (+ routed_rows, MoE configs)
         serve.decode.wait    the wait for that step
 
@@ -637,10 +639,14 @@ class PagedServeEngine:
                     [i for i, sl in enumerate(slots)
                      if sl is not None and lens[i] > 0]
                 # every slot outside ``active`` sits at length 0, so the
-                # rows the step attends, sum(lens[active] + 1), is one sum
+                # rows the step attends, sum(lens[active] + 1), is one sum;
+                # the kernel fetches ceil((lens + 1) / page) pages a slot
+                # and a layer, every slot at least one
                 if active:
                     with span("serve.decode", active=len(active),
-                              kv_rows=int(lens.sum()) + len(active)) as sp:
+                              kv_rows=int(lens.sum()) + len(active),
+                              kv_pages=int(((lens + self.page)
+                                            // self.page).sum())) as sp:
                         # jnp.array (not asarray): asarray zero-copies
                         # numpy on CPU, so the async decode would alias
                         # these host buffers while the scheduler keeps

@@ -312,6 +312,129 @@ def test_paged_decode_latent_mode(dt):
                   ).max() > 10 * tol
 
 
+def _nan_tailed_case(B, NB, page, lens, seed):
+    """Pools whose live pages are random and whose every other block is
+    NaN, and two tables: ``tables`` points each request's tail past its
+    last live page at NaN blocks, ``clean`` at the finite null block 0."""
+    rng = np.random.RandomState(seed)
+    n_live = [-(-n // page) for n in lens]
+    P = 1 + sum(n_live) + B * NB                 # null + live + NaN tails
+    live = rng.permutation(np.arange(1, 1 + sum(n_live)))
+    tables = np.zeros((B, NB), np.int32)
+    clean = np.zeros((B, NB), np.int32)
+    nan_blocks = iter(range(1 + sum(n_live), P))
+    at = 0
+    for b in range(B):
+        clean[b, :n_live[b]] = tables[b, :n_live[b]] = \
+            live[at:at + n_live[b]]
+        at += n_live[b]
+        for j in range(n_live[b], NB):
+            tables[b, j] = next(nan_blocks)
+    is_nan = np.zeros(P, bool)
+    is_nan[1 + sum(n_live):] = True
+    return rng, P, is_nan, jnp.asarray(tables), jnp.asarray(clean)
+
+
+#: lengths of 1, a page edge, mid-page and the full table (3 pages of 128)
+_EDGE_LENS = [1, 128, 200, 384]
+
+
+@pytest.mark.parametrize("kv_step", ["planned", 1])
+@pytest.mark.parametrize("KV,G", [(1, 8), (2, 4), (4, 7), (8, 4)])
+def test_paged_decode_reads_no_dead_page(KV, G, kv_step):
+    """Table slots past each request's last live page point at blocks of
+    NaN: the kernel — all KV heads of a page a grid step as planned, or
+    one — equals the reference over a table whose tails are finite, and
+    is finite, so no dead page enters the result."""
+    B, NB, page, hd = len(_EDGE_LENS), 3, 128, 32
+    rng, P, is_nan, tables, clean = _nan_tailed_case(
+        B, NB, page, _EDGE_LENS, seed=KV)
+    q = jnp.asarray(rng.randn(B, KV * G, hd), jnp.float32)
+    shape = (P, KV, page, hd)
+    k = np.where(is_nan[:, None, None, None], np.nan, rng.randn(*shape))
+    v = np.where(is_nan[:, None, None, None], np.nan, rng.randn(*shape))
+    k, v = jnp.asarray(k, jnp.float32), jnp.asarray(v, jnp.float32)
+    kv_len = jnp.asarray(_EDGE_LENS, jnp.int32)
+    from repro.kernels import plan_for
+    pin = {} if kv_step == "planned" else {"kv_step": kv_step}
+    plan = plan_for("paged_decode_attention",
+                    {"B": B, "T": NB * page, "H": KV * G, "KV": KV,
+                     "hd": hd, "page": page}, dtype=q.dtype, **pin)
+    assert plan.blocks["kv_step"] == (KV if kv_step == "planned"
+                                      else kv_step)
+    y = ops.paged_decode_attention(q, k, v, tables, kv_len, plan=plan)
+    yr = ref.paged_decode_attention_ref(q, k, v, clean, kv_len)
+    assert np.isfinite(np.asarray(y)).all()
+    np.testing.assert_allclose(np.asarray(y), np.asarray(yr), rtol=1e-4,
+                               atol=1e-4)
+
+
+def test_paged_decode_latent_mode_reads_no_dead_page():
+    """The latent mode takes the same clamp: NaN pages in both pools'
+    table tails stay out of the result."""
+    B, NB, page, H, R, rope = len(_EDGE_LENS), 3, 128, 8, 128, 64
+    rng, P, is_nan, tables, clean = _nan_tailed_case(
+        B, NB, page, _EDGE_LENS, seed=9)
+    q = jnp.asarray(rng.randn(B, H, R + rope), jnp.float32)
+    ckv = np.where(is_nan[:, None, None, None], np.nan,
+                   rng.randn(P, 1, page, R))
+    kpe = np.where(is_nan[:, None, None, None], np.nan,
+                   rng.randn(P, 1, rope, page))
+    ckv, kpe = jnp.asarray(ckv, jnp.float32), jnp.asarray(kpe, jnp.float32)
+    kv_len = jnp.asarray(_EDGE_LENS, jnp.int32)
+    scale = 1.0 / np.sqrt(R + rope)
+    y = ops.paged_decode_attention(q, ckv, None, tables, kv_len,
+                                   k_rope_pool=kpe, scale=scale)
+    yr = ref.paged_decode_attention_ref(q, ckv, None, clean, kv_len,
+                                        k_rope_pool=kpe, scale=scale)
+    assert np.isfinite(np.asarray(y)).all()
+    np.testing.assert_allclose(np.asarray(y), np.asarray(yr), rtol=1e-4,
+                               atol=1e-4)
+
+
+@pytest.mark.parametrize("latent", [False, True])
+def test_paged_decode_table_repeats_the_last_live_page(latent,
+                                                       monkeypatch):
+    """The table the kernel's index maps read sends every grid step
+    past a request's last live page to that page, and leaves the live
+    pages as they are: dead steps repeat a block index, so the TPU
+    pipeline copies nothing for them.  Interpret mode cannot show a copy
+    that is never made, so this reads the table operand the kernel is
+    handed."""
+    import jax
+    from repro.kernels import decode_attention
+    seen = []
+    real = decode_attention.pl.pallas_call
+
+    def spy(*a, **kw):
+        call = real(*a, **kw)
+
+        def run(tables, *operands):
+            seen.append(np.asarray(tables))
+            return call(tables, *operands)
+        return run
+
+    monkeypatch.setattr(decode_attention.pl, "pallas_call", spy)
+    page, NB, H, hd = 128, 4, 4, 128
+    lens = np.asarray([0, 1, 128, 129, 300, 512], np.int32)
+    B = len(lens)
+    tables = np.arange(1, 1 + B * NB, dtype=np.int32).reshape(B, NB)
+    P = 1 + B * NB
+    q = jnp.zeros((B, H, hd + 64 * latent), jnp.float32)
+    if latent:
+        pools = (jnp.zeros((P, 1, page, hd)), None)
+        kw = {"k_rope_pool": jnp.zeros((P, 1, 64, page))}
+    else:
+        pools, kw = (jnp.zeros((P, 2, page, hd)),) * 2, {}
+    with jax.disable_jit():                  # concrete operands
+        ops.paged_decode_attention(q, *pools, jnp.asarray(tables),
+                                   jnp.asarray(lens), **kw)
+    got, = seen
+    for b, n in enumerate(lens):
+        last = max(-(-int(n) // page) - 1, 0)
+        assert list(got[b]) == [tables[b, min(j, last)] for j in range(NB)]
+
+
 def test_paged_decode_page_block_mismatch_raises():
     """A plan whose block_kv != the pool page is a geometry bug: raise."""
     import pytest

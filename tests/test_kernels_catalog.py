@@ -147,11 +147,48 @@ def test_plan_aligned_and_budgeted_every_device(kernel, device):
     for name, block in plan.blocks.items():
         if name == "chunk":
             assert block % 8 == 0, plan
+        elif name == "kv_step":              # a head count, not a tile
+            assert BIG_SHAPES[kernel]["KV"] % block == 0, plan
         else:
             assert block % align == 0, plan
     assert plan.vmem_bytes <= plan.vmem_budget, plan
     assert plan.vmem_budget <= spec.vmem_bytes
     assert all(g >= 1 for g in plan.grid), plan
+
+
+@pytest.mark.parametrize("arch", ["qwen2-7b", "mistral-nemo-12b",
+                                  "deepseek-v2-lite-16b"])
+def test_paged_plan_reads_every_head_of_a_page_on_v5e(arch):
+    """On a v5e the chip configurations keep their 512-row page, chosen
+    as for one head, and read all their KV heads of a page in one grid
+    step (4, 8, and the latent pool's one)."""
+    from repro.configs import get_config
+    from repro.serve.paged_cache import default_page_size, pool_heads
+    cfg = get_config(arch)
+    kv, width = pool_heads(cfg)
+    assert default_page_size(cfg, "tpu_v5e", cap=4096) == 512
+    shapes = {"B": 23, "T": 4096, "H": cfg.n_heads, "KV": kv, "hd": width}
+    for pinned in ({}, {"page": 512}):
+        plan = plan_for("paged_decode_attention", dict(shapes, **pinned),
+                        device="tpu_v5e")
+        assert plan.blocks == {"block_kv": 512, "kv_step": kv}, plan
+        assert plan.grid == (23, 8)
+        assert plan.vmem_bytes <= plan.vmem_budget
+
+
+def test_paged_plan_splits_heads_that_do_not_fit():
+    """32 KV heads of 128 (MHA) do not fit one step's budget: the plan
+    takes the largest divisor that does, and the page stays what one
+    head gets."""
+    shapes = {"B": 8, "T": 4096, "H": 32, "KV": 32, "hd": 128}
+    plan = plan_for("paged_decode_attention", shapes, device="tpu_v5e")
+    one = plan_for("paged_decode_attention", dict(shapes, H=1, KV=1),
+                   device="tpu_v5e")
+    step = plan.blocks["kv_step"]
+    assert plan.blocks["block_kv"] == one.blocks["block_kv"] == 512
+    assert 1 < step < 32 and 32 % step == 0, plan
+    assert plan.vmem_bytes <= plan.vmem_budget < 2 * plan.vmem_bytes
+    assert plan.grid == (8 * 32 // step, 8)
 
 
 def test_plan_respects_tight_budget():

@@ -160,12 +160,11 @@ def test_counters_add_up_to_the_run(clean_run):
                 c.args["shed"]) == (0, 0, 0)
 
 
-def test_decode_counters_replay_the_slots(clean_run):
-    """Replaying the chunks and steps in order: a request decodes from
-    the step after its last chunk until it has all its tokens, and each
-    step's ``active`` and ``kv_rows`` are the count of decoding requests
-    and the sum of their lengths + 1 (prompt, then one a step)."""
-    reqs, results, _, _, by = clean_run
+def _replay_decodes(reqs, results, by):
+    """Replay the chunks and steps in order, yielding each decode span
+    with the lengths of the requests decoding in it (request -> length):
+    a request decodes from the step after its last chunk until it has
+    all its tokens (prompt, then one a step)."""
     lens, left = {}, {}
     for ev in sorted(by["serve.prefill"] + by["serve.decode"],
                      key=lambda sp: sp.start):
@@ -177,14 +176,37 @@ def test_decode_counters_replay_the_slots(clean_run):
                 if left[rid]:
                     lens[rid] = s
             continue
-        assert ev.args["active"] == len(lens)
-        assert ev.args["kv_rows"] == sum(n + 1 for n in lens.values())
+        yield ev, dict(lens)
         for rid in list(lens):
             lens[rid] += 1
             left[rid] -= 1
             if not left[rid]:
                 del lens[rid]
     assert not lens and set(left) == set(range(len(reqs)))
+
+
+def test_decode_counters_replay_the_slots(clean_run):
+    """Each step's ``active`` and ``kv_rows`` are the count of decoding
+    requests and the sum of their lengths + 1."""
+    reqs, results, _, _, by = clean_run
+    steps = list(_replay_decodes(reqs, results, by))
+    assert len(steps) == len(by["serve.decode"])
+    for ev, lens in steps:
+        assert ev.args["active"] == len(lens)
+        assert ev.args["kv_rows"] == sum(n + 1 for n in lens.values())
+
+
+def test_decode_kv_pages_count_the_kernels_pages(clean_run):
+    """``kv_pages`` is the pages the paged kernel fetches a layer:
+    ceil((length + 1) / page) for each decoding slot and one for every
+    other slot of the batch, which attends one masked row."""
+    reqs, results, _, _, by = clean_run
+    pages = [(ev.args["kv_pages"],
+              sum(-(-(n + 1) // PAGE) for n in lens.values())
+              + 2 - len(lens))
+             for ev, lens in _replay_decodes(reqs, results, by)]
+    assert pages and all(got == want for got, want in pages)
+    assert max(got for got, _ in pages) >= 5     # multi-page contexts
 
 
 def test_prefill_spans_cover_each_prompt_once(clean_run):

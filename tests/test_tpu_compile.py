@@ -114,6 +114,36 @@ def test_kernel_compiles_for_v5e(kernel, one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_paged_decode_folds_mistral_nemo_heads_on_v5e(one_chip):
+    """At mistral-nemo-12b's decode shape (23 slots, 8 KV heads of 4
+    query heads, hd 128, 8 pages of 512 in a 10-layer stack of 185
+    blocks) the decode step's dispatch decision stays on the kernel,
+    whose plan reads all 8 heads of a page in one grid step, and that
+    folded kernel compiles for a described v5e."""
+    from repro.kernels import dispatch
+    cfg = get_config("mistral-nemo-12b")
+    H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
+    B, NB, page, L, P = 23, 8, 512, 10, 185
+    dec = dispatch.decide(
+        "paged_decode_attention",
+        {"B": B, "T": NB * page, "H": H, "KV": KV, "hd": hd, "page": page},
+        dtype=jnp.bfloat16, device=_DEVICE)
+    assert dec.use_kernel, dec.reason
+    assert dec.plan.blocks == {"block_kv": page, "kv_step": KV}
+    assert dec.plan.grid == (B, NB)
+    bf, i32 = jnp.bfloat16, jnp.int32
+    args = [jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+            for shape, dt in [((B, H, hd), bf),
+                              ((L, P, KV, page, hd), bf),
+                              ((L, P, KV, page, hd), bf),
+                              ((B, NB), i32), ((B,), i32), ((), i32)]]
+    compiled = jax.jit(
+        lambda q, k, v, t, n, i: ops.paged_decode_attention(
+            q, k, v, t, n, i, plan=dec.plan, interpret=False)
+    ).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 @pytest.mark.parametrize("step", ["decode", "prefill"])
 def test_deepseek_v2_lite_share_steps_fit_one_v5e(step, one_chip,
                                                   monkeypatch):
