@@ -1,8 +1,8 @@
 """Continuous-batching serve demo: a ragged request mix (staggered
 arrivals, mixed prompt/output lengths) slot-filled through the
 block-paged KV cache, next to the synchronous bucket engine serving the
-same work — the serve-side front door `benchmarks/serve_bench.py`
-measures.
+same work.  Timings here are the CPU's; speed is measured on the chip
+by `benchmarks/chip/run.py`.
 
     PYTHONPATH=src python examples/serve_demo.py
     PYTHONPATH=src python examples/serve_demo.py --arch mamba2-370m

@@ -39,11 +39,9 @@ Three layers, each testable on its own:
    :math:`\\rho/(1-\\rho)` — is the standard M/G/1-flavoured model; the
    *constants* come from the perf engines, not from hand-waving.
 
-Calibration: :func:`simulate_trace` is a deterministic host-side
-replica of the ``PagedServeEngine`` scheduler (same tick structure:
-retire -> admit -> one prefill chunk per prefilling slot -> one decode
-step for all actives) whose tick/step/chunk counts match the real
-engine *exactly* on any trace; :func:`fit_tick_costs` turns measured
+Calibration: :func:`simulate_trace` drives the ``PagedServeEngine``'s
+own scheduler (:mod:`repro.serve.scheduler`) with no device, so its
+tick/step/chunk counts match the real engine *exactly* on any trace; :func:`fit_tick_costs` turns measured
 walls into per-primitive costs so predicted and measured per-token
 latency can be compared within a tolerance band
 (``tests/test_fleet.py``).
@@ -51,7 +49,6 @@ latency can be compared within a tolerance band
 
 from __future__ import annotations
 
-import collections
 import dataclasses
 import math
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple, Union
@@ -65,7 +62,10 @@ from repro.models.config import ModelConfig
 from repro.perf.hlo_ir import BYTES_PER_ELEM, KernelGraph, KernelOp
 from repro.perf.pipeline import predict
 from repro.perf.report import Report
-from repro.serve.api import Request, as_requests
+from repro.serve.api import Request
+from repro.serve.blocks import BlockAllocator
+from repro.serve.resilience import shed_policies
+from repro.serve.scheduler import Scheduler
 
 __all__ = ["analytic_graphs", "hlo_graphs", "ServeCost", "serve_cost",
            "request_work_s", "token_latency_s", "ttft_s", "p99_latency_s",
@@ -422,8 +422,7 @@ def max_sustainable_qps(scn: TrafficScenario, cost: ServeCost, *,
 @dataclasses.dataclass(frozen=True)
 class SimStats:
     """Tick accounting of one simulated trace (field names match the
-    serve layer's RunStats where they overlap).  The resilience counters
-    default to 0 so pre-resilience constructor calls keep working."""
+    serve layer's RunStats where they overlap)."""
 
     requests: int
     tokens: int
@@ -448,270 +447,34 @@ def simulate_trace(trace: Sequence[Union[Request, Tuple]], *,
                    admission=None, max_preemptions: int = 8,
                    fault_plan=None,
                    max_ticks: Optional[int] = None) -> SimStats:
-    """Replay the ``PagedServeEngine`` scheduler on the host — no model,
-    no jax — and return its tick accounting.
-
-    Models a ``prefix_cache=False`` engine (the calibration baseline:
-    block sharing changes *which* chunks run, not the tick structure's
-    cost shape).  The tick loop mirrors ``PagedServeEngine.run``
-    step-for-step — the canonical 8-step order documented in
-    :mod:`repro.serve.resilience`: faults, cancellations, timeouts,
-    forced preemptions, shed (queue cap then the pluggable policy), FIFO
-    admission under slot + *prompt*-block backpressure, one prefill
-    chunk per prefilling slot, then block growth (exhaustion preempts
-    victims latest-admitted first) and one decode step for all actives.
-    ``ticks`` / ``decode_steps`` / ``prefill_chunks`` and every
-    resilience counter match the real engine exactly on any trace —
-    including overload traces with deadlines, bounded queues, and a
-    ``FaultPlan`` — pinned by ``tests/test_fleet.py``.  Pass the *same*
-    ``admission`` policy and ``fault_plan`` objects the engine ran with:
-    both are pure host logic whose effects are functions of the tick, so
-    sharing the instances is safe.
-    """
-    from repro.serve.resilience import (OK, PREEMPTED, QueueCapPolicy,
-                                        queue_entries)
-    reqs = as_requests(trace)
-    nb_table = math.ceil(max_len / page)
+    """The tick accounting of a ``prefix_cache=False`` engine on
+    ``trace``: the engine's own :class:`~repro.serve.scheduler.Scheduler`
+    over a pool-less :class:`~repro.serve.blocks.BlockAllocator`,
+    every token 0, no model and no device.  Pass the *same*
+    ``admission`` and ``fault_plan`` objects the engine ran with: both
+    are pure functions of the tick."""
     if n_blocks is None:
-        n_blocks = max_batch * nb_table + 1
-    capacity = n_blocks - 1                          # null block reserved
-    for i, r in enumerate(reqs):
-        s = r.prompt.shape[0]
-        if s + r.n_steps > max_len:
-            raise ValueError(f"request {i} does not fit max_len {max_len}")
-        if math.ceil((s + r.n_steps) / page) > capacity:
-            raise ValueError(
-                f"request {i} needs {math.ceil((s + r.n_steps) / page)} "
-                f"blocks but the pool's capacity is {capacity} blocks")
-
-    policies = []
-    if max_queue is not None:
-        policies.append(QueueCapPolicy(max_queue))
-    if admission is not None:
-        policies.append(admission)
-
-    queue = collections.deque(
-        sorted(range(len(reqs)), key=lambda i: (reqs[i].arrival, i)))
-    # slot state: None or [req_idx, filled, remaining, blocks, kv_len, seq]
-    slots: List[Optional[list]] = [None] * max_batch
-    free_blocks = capacity
-    seized: List[list] = []                          # [release_tick, k]
-    seq_counter = 0
-    emitted = [0] * len(reqs)                        # tokens kept per request
-    preempt_count = [0] * len(reqs)
-    done = [False] * len(reqs)
-
-    tick = decode_steps = prefill_chunks = 0
-    n_ok = n_shed = n_timeout = n_cancel = n_preempt = n_stalled = 0
-    occupancy: List[float] = []
-
-    def finish(rid: int, status: str) -> None:
-        done[rid] = True
-        nonlocal n_ok
-        if status == OK:
-            n_ok += 1
-
-    def clear_slot(si: int) -> None:
-        nonlocal free_blocks
-        free_blocks += slots[si][3]
-        slots[si] = None
-
-    def drop_queued(rids, status: str) -> None:
-        nonlocal queue
-        dropped = set(rids)
-        if not dropped:
-            return
-        queue = collections.deque(r for r in queue if r not in dropped)
-        for rid in rids:
-            finish(rid, status)
-
-    def preempt(si: int) -> None:
-        nonlocal n_preempt
-        rid = slots[si][0]
-        clear_slot(si)
-        preempt_count[rid] += 1
-        n_preempt += 1
-        if preempt_count[rid] > max_preemptions:
-            finish(rid, PREEMPTED)                   # partial tokens kept
-            return
-        emitted[rid] = 0                             # recompute from scratch
-        key = (reqs[rid].arrival, rid)
-        pos = 0
-        for pos, q in enumerate(queue):              # sorted re-insert
-            if (reqs[q].arrival, q) > key:
-                break
-        else:
-            pos = len(queue)
-        queue.insert(pos, rid)
-
-    def victims_latest_first() -> List[int]:
-        held = [(slots[si][5], si) for si in range(max_batch)
-                if slots[si] is not None]
-        return [si for _, si in sorted(held, reverse=True)]
-
-    while queue or any(s is not None for s in slots):
-        if max_ticks is not None and tick >= max_ticks:
-            raise RuntimeError(
-                f"simulated scheduler exceeded max_ticks={max_ticks} — "
-                "deadlock canary tripped")
-
-        # 1. faults: release expired seizures, seize for faults firing now
-        stalled = False
-        if fault_plan is not None:
-            keep = []
-            for rel in seized:
-                if rel[0] <= tick:
-                    free_blocks += rel[1]
-                else:
-                    keep.append(rel)
-            seized = keep
-            for f in fault_plan.seizures(tick):
-                k = free_blocks if f.n is None else min(f.n, free_blocks)
-                if k:
-                    free_blocks -= k
-                    seized.append([tick + f.duration, k])
-            stalled = fault_plan.stalled(tick)
-            if stalled:
-                n_stalled += 1
-
-        # 2. cancellations, then 3. timeouts (queued, then in-flight)
-        cancelled = [rid for rid in queue
-                     if reqs[rid].cancel_at is not None
-                     and tick >= reqs[rid].cancel_at]
-        drop_queued(cancelled, "CANCELLED")
-        n_cancel += len(cancelled)
-        for si in range(max_batch):
-            if slots[si] is None:
-                continue
-            r = reqs[slots[si][0]]
-            if r.cancel_at is not None and tick >= r.cancel_at:
-                rid = slots[si][0]
-                clear_slot(si)
-                finish(rid, "CANCELLED")
-                n_cancel += 1
-        timed_out = [rid for rid in queue
-                     if reqs[rid].deadline is not None
-                     and tick > reqs[rid].deadline]
-        drop_queued(timed_out, "TIMEOUT")
-        n_timeout += len(timed_out)
-        for si in range(max_batch):
-            if slots[si] is None:
-                continue
-            r = reqs[slots[si][0]]
-            if r.deadline is not None and tick > r.deadline:
-                rid = slots[si][0]
-                clear_slot(si)
-                finish(rid, "TIMEOUT")
-                n_timeout += 1
-
-        # 3b. fault-forced preemptions (latest-admitted first)
-        if fault_plan is not None:
-            for si in victims_latest_first()[
-                    :fault_plan.forced_preemptions(tick)]:
-                preempt(si)
-
-        # 4. shed: queue-cap bound first, then the pluggable policy
-        if policies:
-            for policy in policies:
-                waiting = [rid for rid in queue if reqs[rid].arrival <= tick]
-                if not waiting:
-                    break
-                entries = queue_entries(tick, waiting, reqs, prefill_chunk)
-                verdicts = dict(policy.shed(tick, entries))
-                drop_queued(list(verdicts), "SHED")
-                n_shed += len(verdicts)
-
-        # 5. admit (FIFO while a slot and the PROMPT reservation fit)
-        while not stalled and queue and reqs[queue[0]].arrival <= tick:
-            free_slots = [i for i, s in enumerate(slots) if s is None]
-            if not free_slots:
-                break
-            rid = queue[0]
-            r = reqs[rid]
-            need = max(1, math.ceil(r.prompt.shape[0] / page))
-            if need > free_blocks:
-                break                                # wait for retirements
-            queue.popleft()
-            free_blocks -= need
-            si = free_slots[0]
-            slots[si] = [rid, 0, r.n_steps, need, 0, seq_counter]
-            seq_counter += 1
-
-        occupancy.append((capacity - free_blocks) / capacity
-                         if capacity else 0.0)
-
-        # 6. one prefill chunk per PREFILLING slot
-        for si in range(max_batch):
-            slot = slots[si]
-            if stalled or slot is None or slot[4] > 0:
-                continue
-            rid = slot[0]
-            s = reqs[rid].prompt.shape[0]
-            slot[1] = min(s, slot[1] + prefill_chunk)
-            prefill_chunks += 1
-            if slot[1] == s:                         # prefill done -> ACTIVE
-                emitted[rid] += 1
-                slot[2] -= 1
-                slot[4] = s
-                if slot[2] == 0:
-                    clear_slot(si)
-                    finish(rid, OK)
-
-        # 7a. grow: ACTIVE slots crossing a page boundary allocate their
-        # next block; exhaustion preempts latest-admitted first
-        for si in range(max_batch):
-            if stalled:
-                break
-            slot = slots[si]
-            if slot is None or slot[4] == 0:
-                continue
-            if slot[4] < slot[3] * page:
-                continue                             # page not full yet
-            got = free_blocks >= 1
-            if not got:
-                for vi in victims_latest_first():
-                    victim_is_self = vi == si
-                    preempt(vi)
-                    if victim_is_self:
-                        break
-                    if free_blocks >= 1:
-                        got = True
-                        break
-            if not got or slots[si] is None:
-                continue                             # grower was evicted
-            free_blocks -= 1
-            slot[3] += 1
-
-        # 7b. one decode step for every ACTIVE slot
-        actives = [] if stalled else \
-            [si for si in range(max_batch)
-             if slots[si] is not None and slots[si][4] > 0]
-        if actives:
-            decode_steps += 1
-            for si in actives:
-                slot = slots[si]
-                rid = slot[0]
-                emitted[rid] += 1
-                slot[2] -= 1
-                slot[4] += 1
-                if slot[2] == 0:
-                    clear_slot(si)
-                    finish(rid, OK)
-        tick += 1
-
-    # mirror the engine: a run ending inside a seizure window hands the
-    # fault-held blocks back before the pool accounting is reported
-    for rel in seized:
-        free_blocks += rel[1]
-    seized = []
-
-    return SimStats(
-        requests=len(reqs), tokens=sum(emitted), ticks=tick,
-        decode_steps=decode_steps, prefill_chunks=prefill_chunks,
-        occupancy_mean=float(np.mean(occupancy)) if occupancy else 0.0,
-        occupancy_max=float(np.max(occupancy)) if occupancy else 0.0,
-        completed=n_ok, shed=n_shed, timeouts=n_timeout,
-        cancelled=n_cancel, preemptions=n_preempt, stalled_ticks=n_stalled)
+        n_blocks = max_batch * math.ceil(max_len / page) + 1
+    sch = Scheduler(trace, BlockAllocator(n_blocks, page), max_len=max_len,
+                    max_batch=max_batch, page=page,
+                    prefill_chunk=prefill_chunk, prefix_cache=False,
+                    policies=shed_policies(max_queue, admission),
+                    max_preemptions=max_preemptions, fault_plan=fault_plan,
+                    max_ticks=max_ticks)
+    toks = np.zeros(max_batch, np.int32)
+    while sch.running():
+        sch.control()
+        sch.admit()
+        for si, *_ in sch.prefill_work():
+            sch.prefilled(si, 0)
+        sch.grow()
+        active = sch.decode_batch()
+        if active:
+            sch.decoded(active, toks)
+        sch.end_tick()
+    run = sch.stats()
+    return SimStats(**{f.name: getattr(run, f.name)
+                       for f in dataclasses.fields(SimStats)})
 
 
 @dataclasses.dataclass(frozen=True)

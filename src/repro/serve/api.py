@@ -3,8 +3,7 @@
 Before this module, :class:`~repro.serve.engine.ServeEngine` consumed
 padded ``(B, S)`` token matrices while :class:`PagedServeEngine.run`
 took bare ``(prompt, n_steps, arrival)`` tuples and returned an ad-hoc
-stats dict — every consumer (bench, demo, tests, and now the fleet
-planner) re-invented the conversion.  The typed surface is:
+stats dict — every consumer re-invented the conversion.  The typed surface is:
 
 * :class:`Request` — one serve request: prompt tokens, tokens to
   generate, arrival tick;

@@ -1,13 +1,9 @@
 """Graceful-degradation policies for the paged serve engine.
 
-The scheduler in :mod:`repro.serve.paged_engine` used to have exactly
-one failure behavior: oversized requests fail fast at validation.
-Everything else — pool exhaustion mid-flight, unbounded arrival queues,
-slow requests holding blocks forever — either backpressured silently or
-degraded every other request's latency.  This module holds the three
-host-side pieces that give the scheduler *terminal states other than
-OK*, plus the deterministic fault-injection harness the chaos suite
-drives them with:
+Host-side pieces that give the paged scheduler
+(:mod:`repro.serve.scheduler`, which owns the tick order) terminal
+states other than OK, plus the deterministic fault-injection harness the
+chaos suite drives them with:
 
 * **Terminal statuses** — every request ends in exactly one of
   :data:`OK` / :data:`TIMEOUT` / :data:`CANCELLED` / :data:`SHED` /
@@ -25,37 +21,16 @@ drives them with:
   :class:`DeadlineAwareShed` sheds requests whose deadline is already
   unreachable even on an idle engine (``tick + min_service_ticks - 1 >
   deadline``) so doomed work never occupies a slot.  Policies are pure
-  host logic over :class:`QueueEntry` views, so the fleet planner's
-  scheduler replica (:func:`repro.fleet.capacity.simulate_trace`) runs
-  the *same* policy objects tick-for-tick.
+  host logic over :class:`QueueEntry` views, so a host-only replica of
+  the scheduler runs the *same* policy objects tick-for-tick.
 
 * **FaultPlan** — a deterministic schedule of injected faults:
   ``exhaust`` (seize free blocks from the allocator for a window),
   ``preempt`` (force victim preemptions), ``stall`` (the engine loses
   whole ticks of data-plane work while deadlines keep aging).  Effects
   are a pure function of the tick, so the same plan replays identically
-  on the real engine and on the host replica, and
-  ``PagedKVCache.check_invariants()`` can be asserted after every tick
-  under test.
-
-The scheduler's tick order with resilience enabled (shared verbatim by
-``PagedServeEngine.run`` and ``simulate_trace``)::
-
-    1. faults      release expired seizures; seize blocks for exhaust
-                   faults firing now; note stall/forced-preempt effects
-    2. cancel      cancel_at <= tick   -> CANCELLED (queued or in-flight)
-    3. timeout     deadline  <  tick   -> TIMEOUT   (queued or in-flight)
-    4. force-preempt   victims latest-admitted-first (fault-injected)
-    5. shed        queue-cap bound, then the pluggable policy -> SHED
-    6. admit       FIFO while a slot + the PROMPT block reservation fit
-    7. prefill     one chunk per PREFILLING slot        [skipped if stalled]
-    8. decode      grow each ACTIVE slot's block on page boundary —
-                   alloc None preempts victims latest-admitted-first —
-                   then one decode step for all actives [skipped if stalled]
-
-Steps 6-8 are the data plane (a stalled tick skips them); steps 1-5 are
-the control plane and always run, which is what makes deadlines honest
-under stalls.
+  on the real engine and on a host replica, and the allocator's
+  ``check_invariants()`` can be asserted after every tick under test.
 """
 
 from __future__ import annotations
@@ -68,7 +43,8 @@ import numpy as np
 
 __all__ = ["OK", "TIMEOUT", "CANCELLED", "SHED", "PREEMPTED", "STATUSES",
            "QueueEntry", "AdmissionPolicy", "FIFOPolicy", "QueueCapPolicy",
-           "DeadlineAwareShed", "Fault", "FaultPlan", "min_service_ticks"]
+           "DeadlineAwareShed", "Fault", "FaultPlan", "min_service_ticks",
+           "shed_policies"]
 
 # -- terminal states --------------------------------------------------------
 
@@ -112,7 +88,7 @@ class AdmissionPolicy:
     ``shed(tick, queue)`` sees the waiting queue (arrival <= tick, FIFO
     order) and returns ``(rid, reason)`` pairs to reject this tick.  The
     base class sheds nothing.  Policies must be deterministic functions
-    of their inputs — the fleet replica replays them tick-for-tick.
+    of their inputs — a host replica replays them tick-for-tick.
     """
 
     name = "fifo"
@@ -183,11 +159,25 @@ class DeadlineAwareShed(AdmissionPolicy):
         return out
 
 
+def shed_policies(max_queue: Optional[int] = None,
+                  admission: Optional[AdmissionPolicy] = None
+                  ) -> List[AdmissionPolicy]:
+    """The policies a scheduler runs each tick, in order: the
+    ``max_queue`` bound first (so the deque stays bounded), then the
+    pluggable ``admission`` policy; both see the same QueueEntry view."""
+    policies: List[AdmissionPolicy] = []
+    if max_queue is not None:
+        policies.append(QueueCapPolicy(max_queue))
+    if admission is not None:
+        policies.append(admission)
+    return policies
+
+
 def queue_entries(tick: int, waiting: Sequence[int], reqs,
                   chunk: int) -> List[QueueEntry]:
     """Policy view of the waiting queue (arrival <= tick), FIFO order.
-    Shared by the engine and the fleet replica so both hand policies
-    byte-identical inputs."""
+    The scheduler builds it for every policy, so each sees the same
+    inputs."""
     out = []
     for rid in waiting:
         r = reqs[rid]
@@ -257,7 +247,7 @@ class FaultPlan:
     """A deterministic, replayable schedule of injected faults.
 
     Effects are a pure function of the tick — the plan holds no run
-    state — so one plan drives the real engine and the fleet replica
+    state — so one plan drives the real engine and a host replica
     identically, and re-running a plan reproduces the failure
     bit-for-bit.  ``seed`` only matters to :meth:`random`, which draws
     a reproducible chaos schedule from it.

@@ -1,12 +1,8 @@
 """Registry of serve trace generators.
 
-These used to live as module-level helpers in
-``benchmarks/serve_bench.py``; the fleet planner's traffic scenarios
-need to replay the *same* request mixes the bench measures, so the
-generators are promoted here behind a tiny registry and both consumers
-draw from it.  The rng draw order of every generator is kept exactly as
-the bench had it — the committed ``BENCH_serve.json`` trend rows stay
-comparable across the move.
+Named request mixes (the base Poisson mix, a shared system prompt,
+long prompts among short ones, an overload burst with deadlines) that
+the tests and examples replay by name, behind a tiny registry.
 
 A trace generator has the signature::
 
@@ -31,9 +27,9 @@ __all__ = ["register_trace", "get_trace", "list_traces",
            "make_trace", "make_shared_trace", "make_longprompt_trace",
            "make_overload_trace"]
 
-# defaults shared with benchmarks/serve_bench.py: requests are clamped
-# to a 128-token engine bucket; the shared-prefix recipe fixes a
-# 256-token (2-page) system prompt inside a 384-token bucket
+# requests are clamped to a 128-token engine bucket; the shared-prefix
+# recipe fixes a 256-token (2-page) system prompt inside a 384-token
+# bucket
 TRACE_MAX_LEN = 128
 SHARED_PREFIX_LEN = 256
 

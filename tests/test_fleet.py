@@ -318,9 +318,10 @@ _TRACES = {
 
 
 def test_simulate_trace_matches_engine_tick_accounting(paged_engine):
-    """The host replica must agree with the real scheduler EXACTLY on
-    ticks, decode steps and prefill chunks — that is what makes fitted
-    tick costs transferable to unseen traces."""
+    """The dry drive and the engine's data plane call the scheduler's
+    phases alike, so they agree EXACTLY on ticks, decode steps and
+    prefill chunks — that is what makes fitted tick costs transferable
+    to unseen traces."""
     cfg, eng = paged_engine
     for name, specs in _TRACES.items():
         trace = _trace(cfg, specs)

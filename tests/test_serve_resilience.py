@@ -432,13 +432,14 @@ def test_check_invariants_flag_without_faults():
 
 
 # ---------------------------------------------------------------------------
-# The fleet replica stays tick-exact under resilience
+# The dry drive stays tick-exact under resilience
 # ---------------------------------------------------------------------------
 
 def test_simulate_trace_tick_exact_on_overload_with_faults():
     """The calibration contract extended to the degraded regime: same
-    trace, same policies, same FaultPlan — every tick counter and every
-    resilience counter must match the real engine exactly."""
+    trace, same policies, same FaultPlan — the engine's data plane and
+    the dry drive call the scheduler's phases alike, so every tick
+    counter and every resilience counter matches exactly."""
     from repro.serve.traces import get_trace
     trace = get_trace("overload")(10, CFG.vocab_size, seed=3)
     plan = FaultPlan(faults=[Fault("exhaust", tick=4, n=2, duration=3),
